@@ -20,10 +20,27 @@ import glob
 import json
 import os
 
-# TPU v5e-like hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports.
+# TPU v5e: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of interconnect per chip (taken
+# here as four links of 50 GB/s).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+# the dry-run artifacts are compiled for a v5e target
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to PEAKS with their "
+                         f"source") from None
+
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts", "dryrun")
 
@@ -48,9 +65,8 @@ def fused_relax_roofline(scale: int = 10, deg: int = 8,
                     scheduled compare-plane min per edge slot)
 
     ``achieved_*`` divides those totals by measured wall time;
-    ``peak_frac_*`` compares against the v5e-like constants at the top
-    of this module (tiny on a CPU interpreter — the point is the
-    instrumentation, which carries unchanged to a real TPU run).
+    ``peak_frac_*`` compares against the running device's entry in
+    :data:`PEAKS` (a device without one, such as the CPU, raises).
     ``rounds_per_invocation`` is the fusion win itself (1.0 ≡ unfused).
     """
     import time
@@ -61,6 +77,7 @@ def fused_relax_roofline(scale: int = 10, deg: int = 8,
     from repro.core.sssp import sssp
     from repro.data.generators import kronecker
 
+    pk = peaks(jax.devices()[0].device_kind)
     g = kronecker(scale, deg, seed=2)
     dg = g.to_device()
     source = int(np.argmax(np.asarray(g.deg)))
@@ -98,15 +115,16 @@ def fused_relax_roofline(scale: int = 10, deg: int = 8,
         "flops_per_invocation": flops / inv,
         "achieved_bw": byts / time_s,
         "achieved_flops": flops / time_s,
-        "peak_frac_bw": byts / time_s / HBM_BW,
-        "peak_frac_flops": flops / time_s / PEAK_FLOPS,
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": byts / HBM_BW,
+        "peak_frac_bw": byts / time_s / pk["hbm_bw"],
+        "peak_frac_flops": flops / time_s / pk["flops"],
+        "compute_s": flops / pk["flops"],
+        "memory_s": byts / pk["hbm_bw"],
         "collective_s": 0.0,
         "useful_ratio": None,
-        "roofline_fraction": ((flops / PEAK_FLOPS) /
-                              max(flops / PEAK_FLOPS, byts / HBM_BW)),
-        "dominant": ("memory" if byts / HBM_BW > flops / PEAK_FLOPS
+        "roofline_fraction": ((flops / pk["flops"]) /
+                              max(flops / pk["flops"],
+                                  byts / pk["hbm_bw"])),
+        "dominant": ("memory" if byts / pk["hbm_bw"] > flops / pk["flops"]
                      else "compute"),
     }
 
@@ -127,7 +145,8 @@ def model_flops_per_device(art: dict) -> float | None:
     return 2.0 * n_active * tokens / n_dev
 
 
-def analyze(art: dict) -> dict:
+def analyze(art: dict, device_kind: str = DRYRUN_KIND) -> dict:
+    pk = peaks(device_kind)
     cost = art.get("cost", {})
     coll = art.get("collectives", {})
     # cost_analysis (and the HLO text) count scan/while bodies ONCE; the
@@ -140,9 +159,9 @@ def analyze(art: dict) -> dict:
     byts = cost.get("bytes accessed", 0.0) * mult
     cbytes = float(coll.get("total", 0)) * mult
     terms = {
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": byts / HBM_BW,
-        "collective_s": cbytes / ICI_BW,
+        "compute_s": flops / pk["flops"],
+        "memory_s": byts / pk["hbm_bw"],
+        "collective_s": cbytes / pk["ici_bw"],
     }
     dom = max(terms, key=terms.get)
     bound_s = terms[dom]

@@ -536,6 +536,8 @@ def main() -> None:
     if args.tune_budget < 1:
         ap.error("--tune-budget must be >= 1")
 
+    from repro.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}")
     os.makedirs(ART, exist_ok=True)
     rows = []
     by_section = {}

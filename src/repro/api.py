@@ -477,17 +477,23 @@ class Solver:
                     f"max_w={float(self._host.max_w):.6g})")
 
     def _open_sharded(self, graph):
-        from .core.distributed import shard_blocked, shard_graph
+        from .core.distributed import (blocked_specs, graph_specs,
+                                       place_on_mesh, shard_blocked,
+                                       shard_graph)
         r = self.resolved
         devs = r.resolve_devices()
         devs = tuple(devs) if devs is not None else tuple(jax.devices())
         self._devices = devs
         self._mesh = jax.sharding.Mesh(np.array(devs), ("graph",))
         with profiling.annotate("repro:engine_build:sharded"):
-            self._sg = shard_graph(graph, len(devs))
+            sg = shard_graph(graph, len(devs))
             self._blocked = None
             if r.shard_backend == "blocked":
-                self._blocked = shard_blocked(self._sg, **r.blocked_opts())
+                arrays, bmeta = shard_blocked(sg, **r.blocked_opts())
+                self._blocked = (place_on_mesh(arrays, self._mesh,
+                                               blocked_specs("graph")),
+                                 bmeta)
+            self._sg = place_on_mesh(sg, self._mesh, graph_specs("graph"))
         self._build_landmarks(graph)
 
     def _open_routed(self, graph):

@@ -30,7 +30,9 @@ import dataclasses
 from typing import Optional, Tuple
 
 __all__ = ["ConfigError", "FacadeDeprecationWarning", "EngineConfig",
-           "ResolvedEngine", "TIERS", "SHARD_VERSIONS", "STEP_POLICIES",
+           "ResolvedEngine", "resolve_interpret", "check_kernel_geometry",
+           "KERNEL_TILING", "TIERS",
+           "SHARD_VERSIONS", "STEP_POLICIES",
            "P2P_MODES", "LANDMARK_STRATEGIES"]
 
 TIERS = ("auto", "single", "sharded", "routed")
@@ -74,6 +76,43 @@ def _canonical_shard_backend(name) -> str:
     canon = _canonical_backend(name) if name not in ("segment_min",
                                                      "blocked") else name
     return "blocked" if canon in _BLOCKED_NAMES else canon
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Pallas interpret mode, decided once from the platform.
+
+    ``None`` resolves to the interpreter on CPU and to compiled kernels
+    on a TPU.  ``True`` on a TPU raises: it would time the Pallas
+    interpreter and report it as the kernel.  ``False`` on CPU is kept,
+    for compiling against a described TPU topology.
+    """
+    import jax
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ConfigError(
+            "interpret=True on a TPU runs the Pallas interpreter in place "
+            "of the compiled kernel; leave interpret unset (None)")
+    return bool(interpret)
+
+
+# Mosaic lays a 1-D kernel block out with the XLA tiling of its array,
+# 1024 elements for the edge slabs and per-vertex outputs, and refuses
+# smaller blocks at compile time
+KERNEL_TILING = 1024
+
+
+def check_kernel_geometry(block_v: int, tile_e: int, why: str = "") -> None:
+    """Refuse a blocked geometry the compiled edge_relax kernels cannot
+    take: ``block_v`` and ``tile_e`` must be multiples of
+    :data:`KERNEL_TILING`."""
+    if block_v % KERNEL_TILING or tile_e % KERNEL_TILING:
+        raise ConfigError(
+            f"compiled edge_relax kernels need block_v and tile_e in "
+            f"multiples of {KERNEL_TILING} (the TPU tiling of 1-D arrays); "
+            f"got block_v={block_v}, tile_e={tile_e}{why}; leave them "
+            f"unset, or pass use_kernel=False for the jnp reference")
 
 
 def resolve_devices(devices):
@@ -130,7 +169,9 @@ class EngineConfig:
       distributed engine variant (v1/v2/v3, bucket-fusion waves, v3's
       compact-exchange capacity).
     * ``block_v``/``tile_e``/``use_kernel``/``interpret`` — blocked
-      layout geometry (only meaningful with a blocked backend).
+      layout geometry (only meaningful with a blocked backend);
+      ``interpret=None`` resolves from the platform
+      (:func:`resolve_interpret`).
     * ``max_batch``/``registry_capacity``/``max_pending``/
       ``ecc_batching`` — serving-plane knobs (routed tier and the
       registry/scheduler stack).
@@ -158,7 +199,7 @@ class EngineConfig:
     block_v: Optional[int] = None
     tile_e: Optional[int] = None
     use_kernel: Optional[bool] = None
-    interpret: bool = True
+    interpret: Optional[bool] = None
     # serving plane
     max_batch: int = 8
     registry_capacity: int = 4
@@ -331,6 +372,25 @@ class EngineConfig:
                 "shard_version='v3' (or drop compact_capacity)")
         return self
 
+    def _check_compiled_geometry(self, tier, shard_backend, n, n_shards):
+        """The geometry the compiled kernels will run: the requested (or
+        default) ``block_v``/``tile_e`` and, on the sharded blocked tier
+        of a known graph, ``block_v`` as the layout snaps it to a divisor
+        of the owner block (:func:`~repro.core.graph.shard_block_v`)."""
+        from ..kernels.edge_relax.edge_relax import (DEFAULT_BLOCK_V,
+                                                      DEFAULT_TILE_E)
+        block_v = self.block_v or DEFAULT_BLOCK_V
+        tile_e = self.tile_e or DEFAULT_TILE_E
+        check_kernel_geometry(block_v, tile_e)
+        if tier == "sharded" and shard_backend == "blocked" and n:
+            from .graph import shard_block_v
+            block = -(-int(n) // n_shards)
+            snapped = shard_block_v(block, block_v)
+            check_kernel_geometry(
+                snapped, tile_e,
+                f" (the sharded layout snaps block_v={block_v} to a divisor "
+                f"of the {block}-vertex owner block of {n_shards} shards)")
+
     def resolve(self, *, n: Optional[int] = None, m: Optional[int] = None,
                 n_devices: Optional[int] = None) -> "ResolvedEngine":
         """Resolve the declarative config against a graph/host context.
@@ -414,6 +474,13 @@ class EngineConfig:
                         f"backend is selected (backend={backend!r}, "
                         f"shard_backend={shard_backend!r})")
 
+        interpret = resolve_interpret(self.interpret)
+        if (tier == "single" and self.fused_rounds and not interpret
+                and backend in _BLOCKED_NAMES
+                and self.use_kernel is not False):
+            from ..kernels.edge_relax.edge_relax import FUSED_ON_TPU
+            raise ConfigError(FUSED_ON_TPU)
+
         devices = self.devices
         if devices is not None:
             resolve_devices(devices)     # range-check int indices now
@@ -427,6 +494,9 @@ class EngineConfig:
             n_devices = len(jax.devices())
         if n_devices < 1:
             raise ConfigError("need at least one device")
+        if blocked_anywhere and not interpret \
+                and self.use_kernel is not False:
+            self._check_compiled_geometry(tier, shard_backend, n, n_devices)
 
         return ResolvedEngine(
             tier=tier, backend=backend, shard_backend=shard_backend,
@@ -440,7 +510,7 @@ class EngineConfig:
             shard_threshold_n=self.shard_threshold_n,
             shard_threshold_m=self.shard_threshold_m,
             block_v=self.block_v, tile_e=self.tile_e,
-            use_kernel=self.use_kernel, interpret=self.interpret,
+            use_kernel=self.use_kernel, interpret=interpret,
             max_batch=self.max_batch,
             registry_capacity=self.registry_capacity,
             max_pending=self.max_pending, ecc_batching=self.ecc_batching,

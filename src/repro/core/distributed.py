@@ -60,13 +60,13 @@ from typing import NamedTuple, Optional, Tuple
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import relax, stats, stepping, traversal
-from .config import EngineConfig, as_resolved
+from .config import EngineConfig, as_resolved, resolve_interpret
 from .graph import (DEFAULT_BLOCK_V, DEFAULT_TILE_E, BlockedEdges,
-                    HostGraph, shard_block_v, slice_for_shard)
+                    HostGraph, bucket_tiles, check_layout_bytes,
+                    layout_bytes, shard_block_v, slice_for_shard)
 from .relax import INF, INT_MAX
 from .sssp import (SsspMetrics, _check_goal_bounds, _goal_reached,
                    _zero_metrics, goal_param_array)
@@ -198,6 +198,7 @@ class BlockedShards(NamedTuple):
     tile_dst: jnp.ndarray        # [P, S, NT] int32 dst block per tile
     tile_first: jnp.ndarray      # [P, S, NT] bool forced-first tiles
     bucket_nonempty: jnp.ndarray  # [P, S, NB] bool bucket-has-edges
+    tile_order: jnp.ndarray      # [P, S*NT] int32 tiles by dst block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -217,11 +218,40 @@ def blocked_specs(axis):
     return BlockedShards(*([P(axis)] * len(BlockedShards._fields)))
 
 
+def place_on_mesh(arrays, mesh, specs):
+    """``device_put`` each field of a sharded layout (``ShardedGraph``,
+    ``BlockedShards``) onto ``mesh`` by its PartitionSpec, once, so every
+    shard's slab sits on its owner device."""
+    return type(arrays)(*(jax.device_put(x, NamedSharding(mesh, s))
+                          for x, s in zip(arrays, specs)))
+
+
+def _shard_tiles(g, n_shards: int, block_v: int, tile_e: int):
+    """``(source blocks over all shards, tiles per slab)`` of the stacked
+    layout: every slab of every shard is padded to the worst slab's tile
+    count.  One bincount, no slab materialized."""
+    n = int(np.asarray(g.deg).shape[0])
+    block = -(-n // n_shards)
+    bv = shard_block_v(block, block_v)
+    n_dst = (block * n_shards) // bv
+    tiles = bucket_tiles(g.src, g.dst, src_blocks=n_dst, n_dst_blocks=n_dst,
+                         block_v=bv, tile_e=tile_e)
+    return n_dst, max(int(tiles.sum(axis=1).max()), 1)
+
+
+def shard_layout_bytes(g, n_shards: int, *, block_v: int = DEFAULT_BLOCK_V,
+                       tile_e: int = DEFAULT_TILE_E) -> int:
+    """Padded bytes :func:`shard_blocked` would allocate for ``g``."""
+    n_src, nt = _shard_tiles(g, n_shards, block_v, tile_e)
+    return layout_bytes(n_src * nt, tile_e)
+
+
 def shard_blocked(g, n_shards: Optional[int] = None, *,
                   block_v: int = DEFAULT_BLOCK_V,
                   tile_e: int = DEFAULT_TILE_E,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True
+                  interpret: Optional[bool] = None,
+                  max_bytes: Optional[int] = None
                   ) -> Tuple[BlockedShards, BlockedShardMeta]:
     """Build the stacked per-shard blocked layout for the engines.
 
@@ -231,23 +261,17 @@ def shard_blocked(g, n_shards: Optional[int] = None, *,
     pass the result to ``sssp_distributed*(..., backend="blocked",
     blocked=...)`` so repeated calls don't re-bucket.
 
-    ``use_kernel`` defaults to True.  Historical note: the pre-megakernel
-    ragged-grid bucket kernel's interpreter (a ``lax.while_loop`` of
-    dynamic slices) deterministically miscompiled under multi-device
-    ``shard_map`` SPMD partitioning on jax 0.4.x (output ranges silently
-    dropped, shifting with unrelated program perturbations), so
-    interpret-mode shards used to fall back to the jnp reference.  The
-    engines now relax through the fixed-grid whole-shard partials
-    megakernel (``edge_relax_partials``: one grid step, state in
-    carries), which re-tested clean on jax 0.4.37 across v1/v2/v3 ×
-    {unfused, fused_rounds=4} × 8 shards — bitwise dist/parent/metric
-    parity with the single-device engine — so interpret mode runs the
-    real kernel too.  Pass ``use_kernel=False`` to pin the
+    ``use_kernel`` defaults to True: the engines relax each shard through
+    the gridded ``edge_relax_partials`` kernel (interpreted on CPU,
+    compiled on a TPU).  Pass ``use_kernel=False`` to pin the
     bitwise-identical jnp reference (layout, frontier-compaction
-    schedule, and tile metrics are shared by both paths).
+    schedule, and tile metrics are shared by both paths).  The padded
+    size is checked against ``max_bytes`` (default: the device's memory)
+    before any slab is built.
     """
     if use_kernel is None:
         use_kernel = True
+    interpret = resolve_interpret(interpret)
     if isinstance(g, ShardedGraph):
         if n_shards is None:
             n_shards = int(g.src.shape[0])
@@ -267,21 +291,19 @@ def shard_blocked(g, n_shards: Optional[int] = None, *,
     # arrays materialized): block_v divides the owner block, so the
     # global src-block id is just src // bv and one bincount covers
     # every (src block, dst block) bucket at once
-    n = int(np.asarray(g.deg).shape[0])
-    block = -(-n // n_shards)
-    bv = shard_block_v(block, block_v)
-    n_dst = (block * n_shards) // bv
-    key = (np.asarray(g.src) // bv).astype(np.int64) * n_dst \
-        + np.asarray(g.dst) // bv
-    counts = np.bincount(key, minlength=(block * n_shards // bv) * n_dst)
-    tiles = -(-counts.reshape(-1, n_dst) // tile_e)
-    nt = max(int(tiles.sum(axis=1).max()), 1)
-    bgs = [slice_for_shard(g, q, n_shards, n_tiles=nt, **kw)
+    n_dst, nt = _shard_tiles(g, n_shards, block_v, tile_e)
+    check_layout_bytes(n_dst * nt, tile_e, "shard_blocked", max_bytes)
+    bgs = [slice_for_shard(g, q, n_shards, n_tiles=nt, max_bytes=max_bytes,
+                           **kw)
            for q in range(n_shards)]
-    stacked = BlockedShards(*(
-        jnp.stack([jnp.stack([getattr(slab, f) for slab in bg.slabs])
-                   for bg in bgs])
-        for f in BlockedEdges._fields))
+    slabs = {f: jnp.stack([jnp.stack([getattr(slab, f) for slab in bg.slabs])
+                           for bg in bgs])
+             for f in BlockedEdges._fields}
+    # the partials kernel visits a shard's tiles destination-major across
+    # its slabs; the order is fixed by the layout, so it is sorted here once
+    order = np.argsort(np.asarray(slabs["tile_dst"]).reshape(n_shards, -1),
+                       axis=1, kind="stable").astype(np.int32)
+    stacked = BlockedShards(**slabs, tile_order=jnp.asarray(order))
     meta = BlockedShardMeta(
         block_v=bgs[0].block_v, tile_e=tile_e,
         n_src_blocks=bgs[0].n_blocks, n_dst_blocks=bgs[0].n_dst_blocks,
@@ -398,8 +420,8 @@ def _build_engine(mesh, axes, version, block, n_pad, params, max_iters,
         # the trace ring is computed from replicated values only
         out_specs = out_specs + (P(),)
 
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -638,7 +660,8 @@ def _v1_body(n_pad, block, axes, params, max_iters, goal="tree", batch=False,
                 best_l, win_l, nt, trav, rlx, prn = \
                     relax.blocked_shard_partials_fused(
                         bl.src_local, bl.dst, bl.w, bl.tile_dst,
-                        bl.tile_first, dist_src, paths_src, parent_src,
+                        bl.tile_first, bl.tile_order, dist_src, paths_src,
+                        parent_src,
                         base, lb, ub, block_v=bmeta.block_v,
                         n_dst_blocks=bmeta.n_dst_blocks,
                         tile_e=bmeta.tile_e, use_kernel=bmeta.use_kernel,
@@ -1003,8 +1026,8 @@ def _build_repair_engine(mesh, axes, version, block, n_pad, max_iters,
         return out[0], out[1], out[3]
 
     body = run_v1 if version == "v1" else run_v2
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -1184,7 +1207,7 @@ def _v2_body(n_pad, block, axes, params, max_iters, fused_rounds,
             kernel's scheduled tile pass."""
             return relax.blocked_shard_partials_fused(
                 bl.src_local, bl.dst, bl.w, bl.tile_dst, bl.tile_first,
-                dist_l, paths, parent_l, base, lb, ub,
+                bl.tile_order, dist_l, paths, parent_l, base, lb, ub,
                 block_v=bmeta.block_v, n_dst_blocks=bmeta.n_dst_blocks,
                 tile_e=bmeta.tile_e, use_kernel=bmeta.use_kernel,
                 interpret=bmeta.interpret,

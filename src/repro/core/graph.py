@@ -14,11 +14,13 @@ directed GAPBS graphs the same way).
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from .config import ConfigError, resolve_interpret
 
 RATIO_NUM = 4096          # paper §4.1: RATIO_NUM = 2^12
 ST_NUM = 1024             # paper §4.1: ST_NUM = 2^10
@@ -216,8 +218,75 @@ jax.tree_util.register_pytree_node(
 )
 
 
+# bytes one padded edge slot costs in a blocked layout: src_local int32,
+# dst int32 and w float32; each tile adds its index (tile_dst int32 and
+# tile_first bool)
+SLOT_BYTES = 12
+TILE_INDEX_BYTES = 5
+
+
+def device_memory_bytes() -> Optional[int]:
+    """The default device's memory, where its backend reports it (TPU
+    and GPU do; the CPU backend reports none)."""
+    stats = jax.devices()[0].memory_stats()
+    return None if not stats else stats.get("bytes_limit")
+
+
+def layout_bytes(n_tiles: int, tile_e: int) -> int:
+    """Bytes of ``n_tiles`` padded tiles of a blocked layout."""
+    return int(n_tiles) * (tile_e * SLOT_BYTES + TILE_INDEX_BYTES)
+
+
+def check_layout_bytes(n_tiles: int, tile_e: int, what: str,
+                       max_bytes: Optional[int] = None) -> int:
+    """Padded bytes of a blocked layout, reckoned before it is allocated.
+
+    Raises :class:`~repro.core.config.ConfigError` when they exceed
+    ``max_bytes`` (default: the device's memory), so an oversized layout
+    fails here instead of filling the host.  Returns the byte count.
+    """
+    nbytes = layout_bytes(n_tiles, tile_e)
+    limit = device_memory_bytes() if max_bytes is None else max_bytes
+    if limit is not None and nbytes > limit:
+        raise ConfigError(
+            f"{what}: the padded blocked layout needs {nbytes} bytes "
+            f"({n_tiles} tiles of {tile_e} slots), more than the {limit} "
+            f"bytes available; use the segment_min backend")
+    return nbytes
+
+
+def bucket_tiles(src, dst, *, src_blocks: int, n_dst_blocks: int,
+                 block_v: int, tile_e: int) -> np.ndarray:
+    """Tiles per (source block, destination block) bucket, from one
+    bincount (no slab is materialized): ``[src_blocks, n_dst_blocks]``.
+    ``src`` ids are relative to the layout's first source."""
+    key = (np.asarray(src, np.int64) // block_v) * n_dst_blocks \
+        + np.asarray(dst, np.int64) // block_v
+    counts = np.bincount(key, minlength=src_blocks * n_dst_blocks)
+    return -(-counts.reshape(src_blocks, n_dst_blocks) // tile_e)
+
+
+def _layout_tiles(src, dst, *, src_blocks: int, n_dst_blocks: int,
+                  block_v: int, tile_e: int) -> int:
+    """Tiles of a layout whose slabs keep their minimal count (>= 1)."""
+    tiles = bucket_tiles(src, dst, src_blocks=src_blocks,
+                         n_dst_blocks=n_dst_blocks, block_v=block_v,
+                         tile_e=tile_e)
+    return int(np.maximum(tiles.sum(axis=1), 1).sum())
+
+
+def blocked_layout_bytes(g, *, block_v: int = DEFAULT_BLOCK_V,
+                         tile_e: int = DEFAULT_TILE_E) -> int:
+    """Padded bytes :func:`build_blocked` would allocate for ``g``."""
+    nb = max(-(-int(np.asarray(g.deg).shape[0]) // block_v), 1)
+    return layout_bytes(_layout_tiles(g.src, g.dst, src_blocks=nb,
+                                      n_dst_blocks=nb, block_v=block_v,
+                                      tile_e=tile_e), tile_e)
+
+
 def bucket_edges(src_local, dst, w, *, n_dst_blocks: int, block_v: int,
-                 tile_e: int, n_tiles: int = 0):
+                 tile_e: int, n_tiles: int = 0,
+                 max_bytes: Optional[int] = None):
     """Bucket one slab's edges by destination block, tile-aligned.
 
     Edges are sorted by ``dst // block_v`` (stable) and each non-empty
@@ -229,6 +298,9 @@ def bucket_edges(src_local, dst, w, *, n_dst_blocks: int, block_v: int,
     ``n_tiles`` > 0 pads the slab to exactly that many tiles (shape
     uniformity across shard slabs under ``shard_map``); 0 keeps the
     minimal count (always >= 1, so the kernel grid is never empty).
+
+    ``max_bytes`` caps the slab's padded bytes (see
+    :func:`check_layout_bytes`).
 
     Returns numpy arrays ``(src_local, dst, w, tile_dst, tile_first,
     bucket_nonempty, tile_ptr)``; ``tile_ptr`` [n_dst_blocks + 1] is the
@@ -251,6 +323,7 @@ def bucket_edges(src_local, dst, w, *, n_dst_blocks: int, block_v: int,
     if n_tiles and n_tiles < nt_min:
         raise ValueError(f"n_tiles={n_tiles} < required {nt_min}")
     nt = n_tiles if n_tiles else nt_min
+    check_layout_bytes(nt, tile_e, "bucket_edges", max_bytes)
     tile_ptr = np.zeros(n_dst_blocks + 1, np.int64)
     np.cumsum(tiles_per, out=tile_ptr[1:])
     s_out = np.zeros(nt * tile_e, np.int32)
@@ -287,30 +360,49 @@ def _slab_edges(s_l, d, ww, *, n_dst_blocks, block_v, tile_e, n_tiles=0):
                         bucket_nonempty=jnp.asarray(bne))
 
 
+def _by_src_block(src, *arrays, block_v: int, n_blocks: int):
+    """Split edge arrays by source block with one stable sort (one
+    ``[lo, hi)`` run per block) instead of one mask per block."""
+    sb = np.asarray(src) // block_v
+    order = np.argsort(sb, kind="stable")
+    bounds = np.searchsorted(sb[order], np.arange(n_blocks + 1))
+    arrays = [np.asarray(a)[order] for a in (src,) + arrays]
+    for b in range(n_blocks):
+        lo, hi = bounds[b], bounds[b + 1]
+        yield tuple(a[lo:hi] for a in arrays)
+
+
 def build_blocked(g, *, block_v: int = DEFAULT_BLOCK_V,
                   tile_e: int = DEFAULT_TILE_E, use_kernel: bool = True,
-                  interpret: bool = True) -> BlockedGraph:
+                  interpret: Optional[bool] = None,
+                  max_bytes: Optional[int] = None) -> BlockedGraph:
     """Pre-bucket a graph (``HostGraph`` or ``DeviceGraph``) for the kernel.
 
     Host-side (concrete shapes are required for the static tile padding);
-    call once per graph, outside ``jit``.
+    call once per graph, outside ``jit``.  The padded size is reckoned
+    from the bucket counts first and checked against ``max_bytes``
+    (default: the device's memory) before any slab is allocated.
     """
+    interpret = resolve_interpret(interpret)
     src = np.asarray(g.src)
     dst = np.asarray(g.dst)
     w = np.asarray(g.w)
     deg = np.asarray(g.deg)
     n = int(deg.shape[0])
     n_blocks = max(-(-n // block_v), 1)
-    sb = src // block_v
+    check_layout_bytes(
+        _layout_tiles(src, dst, src_blocks=n_blocks, n_dst_blocks=n_blocks,
+                      block_v=block_v, tile_e=tile_e),
+        tile_e, "build_blocked", max_bytes)
     slabs = []
     dense_tiles = 0
-    for b in range(n_blocks):
-        m = sb == b
-        slabs.append(_slab_edges(src[m] - b * block_v, dst[m], w[m],
+    for b, (s_b, d_b, w_b) in enumerate(
+            _by_src_block(src, dst, w, block_v=block_v, n_blocks=n_blocks)):
+        slabs.append(_slab_edges(s_b - b * block_v, d_b, w_b,
                                  n_dst_blocks=n_blocks, block_v=block_v,
                                  tile_e=tile_e))
         # what the dense (n_dst_blocks x n_tiles) grid scanned per round
-        dense_tiles += n_blocks * max(-(-int(m.sum()) // tile_e), 1)
+        dense_tiles += n_blocks * max(-(-int(s_b.shape[0]) // tile_e), 1)
     deg_pad = np.zeros(n_blocks * block_v, np.int32)
     deg_pad[:n] = deg
     return BlockedGraph(n=n, block_v=block_v, n_blocks=n_blocks,
@@ -339,7 +431,8 @@ def slice_for_shard(g, shard: int, n_shards: int, *,
                     block_v: int = DEFAULT_BLOCK_V,
                     tile_e: int = DEFAULT_TILE_E, n_tiles: int = 0,
                     use_kernel: bool = True,
-                    interpret: bool = True) -> BlockedGraph:
+                    interpret: Optional[bool] = None,
+                    max_bytes: Optional[int] = None) -> BlockedGraph:
     """Blocked layout for one shard's CSR slice (sources = owner block).
 
     Vertex ownership matches :func:`repro.core.distributed.shard_graph`:
@@ -351,8 +444,10 @@ def slice_for_shard(g, shard: int, n_shards: int, *,
     padded ``n_shards * B`` range — so the per-destination partials line
     up with the engines' ``all_to_all`` exchange.  ``n_tiles`` > 0 pads
     every slab to that tile count (uniform shapes across shards, a
-    ``shard_map`` requirement).
+    ``shard_map`` requirement).  The padded size is checked against
+    ``max_bytes`` (default: the device's memory) before allocating.
     """
+    interpret = resolve_interpret(interpret)
     src = np.asarray(g.src)
     dst = np.asarray(g.dst)
     w = np.asarray(g.w)
@@ -368,15 +463,19 @@ def slice_for_shard(g, shard: int, n_shards: int, *,
     lo = shard * block
     m_shard = (src >= lo) & (src < lo + block)
     src_s, dst_s, w_s = src[m_shard], dst[m_shard], w[m_shard]
-    sb = (src_s - lo) // bv
+    check_layout_bytes(
+        n_src_blocks * n_tiles if n_tiles else _layout_tiles(
+            src_s - lo, dst_s, src_blocks=n_src_blocks,
+            n_dst_blocks=n_dst_blocks, block_v=bv, tile_e=tile_e),
+        tile_e, "slice_for_shard", max_bytes)
     slabs = []
     dense_tiles = 0
-    for b in range(n_src_blocks):
-        m = sb == b
-        slabs.append(_slab_edges(src_s[m] - lo - b * bv, dst_s[m], w_s[m],
+    for b, (s_b, d_b, w_b) in enumerate(_by_src_block(
+            src_s - lo, dst_s, w_s, block_v=bv, n_blocks=n_src_blocks)):
+        slabs.append(_slab_edges(s_b - b * bv, d_b, w_b,
                                  n_dst_blocks=n_dst_blocks, block_v=bv,
                                  tile_e=tile_e, n_tiles=n_tiles))
-        dense_tiles += n_dst_blocks * max(-(-int(m.sum()) // tile_e), 1)
+        dense_tiles += n_dst_blocks * max(-(-int(s_b.shape[0]) // tile_e), 1)
     deg_pad = np.zeros(block, np.int32)
     hi = min(lo + block, n)
     if hi > lo:

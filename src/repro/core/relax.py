@@ -30,8 +30,9 @@ Registered backends:
     destination block iterates only its own tile range, and a
     frontier-compaction prepass skips tiles with no frontier source this
     round entirely.  Per-source-block (min, winner) partials are combined
-    with the same deterministic min/min-src rule.  On this CPU container
-    the kernel runs in interpret mode.  The same per-shard machinery
+    with the same deterministic min/min-src rule.  The kernel is
+    interpreted on CPU and compiled on a TPU (``interpret`` resolves from
+    the platform).  The same per-shard machinery
     (:func:`blocked_partials`) backs ``core/distributed.py``'s
     ``backend="blocked"`` inside ``shard_map``.
 
@@ -546,7 +547,8 @@ def blocked_fused_rounds(bg: BlockedGraph, fs: FusedSlab, dist, parent,
 
 
 def blocked_shard_partials_fused(src_local, dst, w, tile_dst, tile_first,
-                                 dist_src, paths_src, parent_src, src_base,
+                                 tile_order, dist_src, paths_src, parent_src,
+                                 src_base,
                                  lb, ub, *, block_v: int, n_dst_blocks: int,
                                  tile_e: int, use_kernel: bool,
                                  interpret: bool, alt_lb=None,
@@ -555,7 +557,8 @@ def blocked_shard_partials_fused(src_local, dst, w, tile_dst, tile_first,
 
     One kernel invocation relaxes ALL of a shard's stacked slabs
     (``src_local``/``dst``/``w`` ``[S, NT*tile_e]``,
-    ``tile_dst``/``tile_first`` ``[S, NT]``) against the shard's local
+    ``tile_dst``/``tile_first`` ``[S, NT]``, ``tile_order`` ``[S*NT]``
+    the destination-major schedule order) against the shard's local
     ``dist_src``/``paths_src``/``parent_src`` slice, folding ``n_trav``/
     ``n_relax``/tile counts into the scheduled tile pass — replacing one
     launch per source block plus the flat O(E) metrics pass.  Returns
@@ -567,7 +570,7 @@ def blocked_shard_partials_fused(src_local, dst, w, tile_dst, tile_first,
     best, win_local, cnt = relax_partials(
         dist_src, paths_src, parent_src,
         (src_local + offs).reshape(-1), dst.reshape(-1), w.reshape(-1),
-        tile_dst.reshape(-1), tile_first.reshape(-1), lb, ub,
+        tile_dst.reshape(-1), tile_first.reshape(-1), tile_order, lb, ub,
         block_v=block_v, tile_e=tile_e, n_dst_blocks=n_dst_blocks,
         use_kernel=use_kernel, interpret=interpret, alt_lb=alt_lb,
         prune_bound=prune_bound)

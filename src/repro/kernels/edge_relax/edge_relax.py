@@ -9,17 +9,21 @@ block:
     win[j]  = min src[e] over the edges achieving out[j]   (deterministic
               parent recovery: smallest source id among the winners)
 
-TPU adaptation (DESIGN.md §2/§5): the MPI CAS loop becomes a dense masked
-min-reduction.  Edges arrive pre-bucketed by (src block, dst block) with
-every bucket padded to a tile boundary — the
-:class:`~repro.core.graph.BlockedGraph` layout — so each tile belongs to
-exactly one destination block and the source-distance block and the
-destination output block both fit in VMEM.  The scatter is expressed as a
-broadcast-compare reduce over the (TILE_E x BLOCK_V) plane, which is
-VPU-shaped (8x128 lanes), avoiding data-dependent writes entirely; tiles
-revisiting the same output block are combined in-place (value min, winner
-min on ties — associative and order-independent, so the accumulation is
-deterministic).
+TPU adaptation: the MPI CAS loop becomes a dense masked min-reduction.
+Edges arrive pre-bucketed by (src block, dst block) with every bucket
+padded to a tile boundary — the :class:`~repro.core.graph.BlockedGraph`
+layout — so each tile belongs to exactly one destination block and the
+source-distance block and the destination output block both fit in VMEM.
+Mosaic has no vector-indexed gather, so both sides are broadcast-compare
+reduces over (TILE_E x BLOCK_V) planes, which are VPU-shaped: the
+source gather ``dist[src]`` selects through an ``src == lane`` plane and
+the scatter-min through a ``dst == lane`` plane, with no data-dependent
+reads or writes; tiles revisiting the same output block are combined
+in-place (value min, winner min on ties — associative and
+order-independent, so the accumulation is deterministic).  Slabs and
+outputs stream as 1-D blocks, which Mosaic lays out with the XLA tiling
+of their arrays (1024 elements), so compiled kernels need ``tile_e`` and
+``block_v`` in multiples of 1024.
 
 **Sparsity-aware ragged grid.**  The grid is 1-D over the slab's tiles
 (``grid=(n_tiles,)``), not the dense ``(n_dst_blocks, n_tiles)`` product:
@@ -43,7 +47,10 @@ schedule while the grid itself stays static (jit/vmap-compatible).
 Destination blocks with no tiles at all are never visited; their output
 range is masked to +inf / INT_MAX after the call.
 
-**Multi-round fused megakernel.**  :func:`edge_relax_fused` executes up
+**Multi-round fused megakernel (interpret mode only).**  It does not
+compile for TPU — whole-slab VMEM residency, vector-indexed gathers and
+an nt x nt compaction plane — and refuses ``interpret=False`` with
+:data:`FUSED_ON_TPU`.  :func:`edge_relax_fused` executes up
 to ``fused_rounds`` complete windowed relaxation rounds in ONE Pallas
 invocation over the whole (concatenated, global-source-id) edge slab.
 The VMEM residency contract: ``dist``/``parent``/``frontier`` live in
@@ -74,25 +81,61 @@ a step, which is what makes in-kernel round chaining exact; during the
 bootstrap step (``lb <= 0``) the upper bound tightens after every
 round, so the wrapper clamps the invocation to a single round there.
 
-:func:`edge_relax_partials` is the single-round partials mode of the
-same tile pass for the sharded engines: one invocation relaxes ALL of a
-shard's source-block slabs (against the shard's local source slice) and
-returns (min, winner) partials plus the in-kernel counter sums, ready
-for the collective exchange — replacing one kernel launch per source
-block and the flat O(E) metrics pass with a single launch per shard.
+:func:`edge_relax_partials` runs the same gridded tile kernel for the
+sharded engines: one invocation relaxes ALL of a shard's source-block
+slabs (each tile fetching its own source block of the shard's local
+state, the schedule ordered by destination block across the slabs) and
+returns (min, winner) partials plus the logical counters summed in the
+kernel, ready for the collective exchange — one launch per shard and no
+flat O(E) metrics pass.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_TILE_E = 512
-DEFAULT_BLOCK_V = 512
+from ...core.config import KERNEL_TILING, ConfigError, resolve_interpret
+
+DEFAULT_TILE_E = 1024
+DEFAULT_BLOCK_V = 1024
 INT_MAX = jnp.iinfo(jnp.int32).max
+
+# counter slots of the fused kernels' in-kernel metric accumulator
+FUSED_COUNTERS = ("n_trav", "n_relax", "n_updates", "n_extended",
+                  "n_rounds", "n_tiles", "n_exec", "n_pruned")
+PARTIAL_COUNTERS = ("n_trav", "n_relax", "n_tiles", "n_pruned")
+
+
+def _compact(active, order):
+    """Stable compaction of the active tiles, visited in ``order``.
+
+    Returns ``(sched, sched_n)``: ``sched[i]`` is the tile to run at grid
+    step ``i`` (the active tiles of ``order``, in that order, then the
+    last active tile repeated so inactive steps never change the block
+    index) and ``sched_n`` the number of active tiles.  A segmented
+    prefix-sum scatter, not an argsort: an active tile's exclusive rank
+    is its slot; inactive tiles scatter to a dropped out-of-range slot.
+    """
+    nt = active.shape[0]
+    act = active[order]
+    pos = jnp.cumsum(act.astype(jnp.int32)) - 1
+    sched_n = pos[-1] + 1
+    idx = jnp.arange(nt, dtype=jnp.int32)
+    sched = jnp.zeros((nt,), jnp.int32).at[
+        jnp.where(act, pos, nt)].set(order, mode="drop")
+    last = sched[jnp.maximum(sched_n - 1, 0)]
+    sched = jnp.where(idx < sched_n, sched, last)
+    return sched, sched_n
+
+
+def _active_tiles(touched, tile_first, tile_e: int):
+    nt = tile_first.shape[0]
+    return touched.reshape(nt, tile_e).any(axis=1) | (tile_first > 0)
 
 
 def schedule_tiles(frontier_block, src_local, w, tile_first, tile_e: int):
@@ -110,68 +153,87 @@ def schedule_tiles(frontier_block, src_local, w, tile_first, tile_e: int):
     the last active tile repeated so inactive steps never change the
     block index — and ``sched_n`` the number of active tiles.
     """
-    nt = tile_first.shape[0]
     touched = (frontier_block[src_local] > 0) & jnp.isfinite(w)
-    active = touched.reshape(nt, tile_e).any(axis=1) | tile_first
-    # segmented prefix-sum scatter: an active tile's exclusive rank is its
-    # slot in the compacted schedule, so layout (dst-sorted) order is
-    # preserved without the O(nt log nt) argsort — inactive tiles scatter
-    # to a dropped out-of-range slot
-    pos = jnp.cumsum(active.astype(jnp.int32)) - 1
-    sched_n = pos[-1] + 1
-    idx = jnp.arange(nt, dtype=jnp.int32)
-    sched = jnp.zeros((nt,), jnp.int32).at[
-        jnp.where(active, pos, nt)].set(idx, mode="drop")
-    last = sched[jnp.maximum(sched_n - 1, 0)]
-    sched = jnp.where(idx < sched_n, sched, last)
-    return sched, sched_n
+    active = _active_tiles(touched, tile_first, tile_e)
+    return _compact(active, jnp.arange(active.shape[0], dtype=jnp.int32))
 
 
-def _kernel(sched_ref, sd_ref, na_ref, lbub_ref, dist_ref, frontier_ref,
-            src_ref, dst_ref, w_ref, *rest, block_v: int,
-            alt: bool = False):
-    if alt:
-        alt_ref, val_ref, win_ref = rest
-    else:
-        val_ref, win_ref = rest
+def _check_compiled_geometry(block_v: int, tile_e: int, interpret: bool):
+    """``EngineConfig.resolve`` refuses other geometries on a TPU
+    (:func:`~repro.core.config.check_kernel_geometry`); Mosaic would."""
+    assert interpret or not (block_v % KERNEL_TILING
+                             or tile_e % KERNEL_TILING), (
+        f"compiled edge_relax kernels need block_v and tile_e in multiples "
+        f"of {KERNEL_TILING}; got block_v={block_v}, tile_e={tile_e}")
+
+
+def _tiles_kernel(sched_ref, sched_src_ref, sched_dst_ref, na_ref, lbub_ref,
+                  dsrc_ref, *rest, block_v: int, alt: bool, counters: bool):
+    """One scheduled edge tile against its source and destination blocks.
+
+    ``dsrc_ref`` is the tile's source block of ``dist`` with non-frontier
+    sources already set to +inf, so the window test alone decides which
+    edges relax.  The source-side gathers and the destination scatter-min
+    are broadcast-compare planes over (tile_e x block_v): Mosaic has no
+    vector-indexed gather, and the planes are VPU-shaped.
+    """
+    rest = list(rest)
+    par_ref = rest.pop(0) if counters else None
+    src_ref, dst_ref, w_ref = rest[:3]
+    rest = rest[3:]
+    alt_ref = rest.pop(0) if alt else None
+    val_ref, win_ref = rest[:2]
+    cnt_ref = rest[2] if counters else None
     i = pl.program_id(0)
-    b = sd_ref[i]                               # this tile's dst block
+    b = sched_dst_ref[i]
+    sb = sched_src_ref[i]
     prev = jnp.maximum(i - 1, 0)
-    is_first = (i == 0) | (sd_ref[i] != sd_ref[prev])
+    is_first = (i == 0) | (b != sched_dst_ref[prev])
 
     @pl.when(is_first)
     def _init():
         val_ref[...] = jnp.full_like(val_ref, jnp.inf)
         win_ref[...] = jnp.full_like(win_ref, INT_MAX)
 
+    if counters:
+        @pl.when(i == 0)
+        def _zero():
+            for k in range(len(PARTIAL_COUNTERS)):
+                cnt_ref[k] = jnp.int32(0)
+
     @pl.when(i < na_ref[0])
     def _accumulate():
         lb = lbub_ref[0]
         ub = lbub_ref[1]
         src = src_ref[...]
-        dst = dst_ref[...]
-        w = w_ref[...]
-        d_src = dist_ref[src]                   # VMEM gather (src block local)
-        front = frontier_ref[src]
+        w = w_ref[...][:, None]
+        dst = dst_ref[...][:, None]
+        te = src.shape[0]
+        bs = dsrc_ref.shape[0]
+        src_lanes = jax.lax.broadcasted_iota(jnp.int32, (te, bs), 1)
+        shit = (src - sb * bs)[:, None] == src_lanes
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (te, block_v), 1)
+        d_src = jnp.min(jnp.where(shit, dsrc_ref[...][None, :], jnp.inf),
+                        axis=1, keepdims=True)
         cand = d_src + w
-        ok = (front > 0) & (cand >= lb) & (cand < ub)
+        in_win = (cand >= lb) & (cand < ub)
+        dhit = dst == b * block_v + lanes
+        ok = in_win
         if alt:
             # ALT goal-directed cut: a candidate whose admissible
             # remaining-distance bound already exceeds the best known
             # s->t length can never improve it (alt_ref is this dst
             # block's slice of the per-vertex bound array)
-            loc = jnp.clip(dst - b * block_v, 0, block_v - 1)
-            ok = ok & (cand + alt_ref[loc] <= lbub_ref[2])
+            alt_e = jnp.min(jnp.where(dhit, alt_ref[...][None, :], jnp.inf),
+                            axis=1, keepdims=True)
+            fail = cand + alt_e > lbub_ref[2]
+            ok = in_win & ~fail
         cand = jnp.where(ok, cand, jnp.inf)
-        # dense scatter-min: [TILE_E, BLOCK_V] compare plane for dst block b
-        cols = b * block_v + jax.lax.broadcasted_iota(
-            jnp.int32, (src.shape[0], block_v), 1)
-        hit = dst[:, None] == cols
-        plane = jnp.where(hit, cand[:, None], jnp.inf)
+        plane = jnp.where(dhit, cand, jnp.inf)
         tile_min = jnp.min(plane, axis=0)       # [BLOCK_V]
-        winners = jnp.where(hit & ok[:, None] & (cand[:, None] <= tile_min),
+        winners = jnp.where(dhit & ok & (cand <= tile_min[None, :]),
                             src[:, None], INT_MAX)
-        tile_win = jnp.min(winners, axis=0)     # [BLOCK_V] block-local src
+        tile_win = jnp.min(winners, axis=0)     # [BLOCK_V]
 
         prev_v = val_ref[...]
         prev_w = win_ref[...]
@@ -182,6 +244,66 @@ def _kernel(sched_ref, sd_ref, na_ref, lbub_ref, dist_ref, frontier_ref,
             better, tile_win,
             jnp.where(tie, jnp.minimum(prev_w, tile_win), prev_w))
 
+        if counters:
+            # the relaxation back along the parent edge never improves,
+            # so it is counted out of n_relax but left in the min
+            p_src = jnp.max(jnp.where(shit, par_ref[...][None, :], -2),
+                            axis=1, keepdims=True)
+            notpar = dst != p_src
+            cnt_ref[0] += jnp.sum(in_win.astype(jnp.int32))
+            cnt_ref[1] += jnp.sum((ok & notpar).astype(jnp.int32))
+            if alt:
+                cnt_ref[3] += jnp.sum((in_win & notpar & fail
+                                       ).astype(jnp.int32))
+
+
+def _relax_tiles(dsrc, parent_src, src, dst, w, sched, sched_src, sched_dst,
+                 sched_n, lbub, alt_lb, *, src_block: int, block_v: int,
+                 tile_e: int, n_out: int, interpret: bool):
+    """Run the scheduled tiles of a slab set in one gridded kernel.
+
+    ``sched``/``sched_src``/``sched_dst`` give each grid step its tile,
+    the tile's ``src_block``-wide source block (into ``dsrc``/
+    ``parent_src``; ``src`` ids index those arrays directly) and its
+    destination block; steps of one destination block are consecutive.
+    With ``parent_src`` the kernel also returns the in-kernel counters
+    ``(n_trav, n_relax, 0, n_pruned)``.
+    """
+    alt = alt_lb is not None
+    counters = parent_src is not None
+    by_src = pl.BlockSpec((src_block,), lambda i, s, ss, *_: (ss[i],))
+    by_tile = pl.BlockSpec((tile_e,), lambda i, s, *_: (s[i],))
+    by_dst = pl.BlockSpec((block_v,), lambda i, s, ss, sd, *_: (sd[i],))
+    in_specs = [by_src] + [by_src] * counters + [by_tile] * 3 + [by_dst] * alt
+    operands = [dsrc] + [parent_src] * counters + [src, dst, w] \
+        + [alt_lb] * alt
+    out_specs = [by_dst, by_dst]
+    out_shape = [jax.ShapeDtypeStruct((n_out,), jnp.float32),
+                 jax.ShapeDtypeStruct((n_out,), jnp.int32)]
+    if counters:
+        out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        out_shape.append(jax.ShapeDtypeStruct((len(PARTIAL_COUNTERS),),
+                                              jnp.int32))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # sched, sched_src, sched_dst, n_active, lbub: the window bounds
+        # are genuinely scalars, which is what SMEM is for
+        num_scalar_prefetch=5,
+        grid=(sched.shape[0],),
+        in_specs=in_specs,
+        out_specs=tuple(out_specs),
+    )
+    return pl.pallas_call(
+        functools.partial(_tiles_kernel, block_v=block_v, alt=alt,
+                          counters=counters),
+        grid_spec=grid_spec,
+        out_shape=tuple(out_shape),
+        interpret=interpret,
+    )(sched, sched_src, sched_dst, sched_n[None], lbub, *operands)
+
+
+def _lbub(lb, ub, *extra):
+    return jnp.stack([jnp.float32(x) for x in (lb, ub) + extra])
+
 
 @functools.partial(jax.jit, static_argnames=("block_v", "tile_e",
                                              "n_dst_blocks", "interpret"))
@@ -189,7 +311,7 @@ def edge_relax(dist_block, frontier_block, src_local, dst_local, w,
                tile_dst, tile_first, bucket_nonempty, lb, ub,
                alt_lb=None, prune_bound=None, *,
                block_v: int = DEFAULT_BLOCK_V, tile_e: int = DEFAULT_TILE_E,
-               n_dst_blocks: int = 1, interpret: bool = True):
+               n_dst_blocks: int = 1, interpret: Optional[bool] = None):
     """Relax one source-block edge slab against its active tile schedule.
 
     dist_block/frontier_block: [Bs] f32 / int8 (src block local).
@@ -207,52 +329,23 @@ def edge_relax(dist_block, frontier_block, src_local, dst_local, w,
     broken toward the smallest source id), plus the number of tiles the
     compacted schedule actually ran.
     """
+    interpret = resolve_interpret(interpret)
+    _check_compiled_geometry(block_v, tile_e, interpret)
     e = src_local.shape[0]
     if e % tile_e != 0 or e == 0:
         raise ValueError(f"slab length {e} is not tile-aligned "
                          f"(tile_e={tile_e}); bucket it with "
                          "repro.core.graph.bucket_edges")
-    nt = e // tile_e
     sched, sched_n = schedule_tiles(frontier_block, src_local, w,
                                     tile_first, tile_e)
-    sched_dst = tile_dst[sched]
+    dsrc = jnp.where(frontier_block > 0, dist_block, jnp.inf)
     alt = alt_lb is not None
-    scal = [jnp.float32(lb), jnp.float32(ub)]
-    if alt:
-        scal.append(jnp.float32(prune_bound))
-    lbub = jnp.stack(scal)
-    n_out = n_dst_blocks * block_v
-
-    # lbub rides in the scalar-prefetch (SMEM) path with the schedule —
-    # window bounds are genuinely scalars, which is what SMEM is for.
-    in_specs = [
-        pl.BlockSpec(dist_block.shape, lambda i, *_: (0,)),
-        pl.BlockSpec(frontier_block.shape, lambda i, *_: (0,)),
-        pl.BlockSpec((tile_e,), lambda i, s, *_: (s[i],)),
-        pl.BlockSpec((tile_e,), lambda i, s, *_: (s[i],)),
-        pl.BlockSpec((tile_e,), lambda i, s, *_: (s[i],)),
-    ]
-    operands = [sched, sched_dst, sched_n[None], lbub, dist_block,
-                frontier_block.astype(jnp.int8), src_local, dst_local, w]
-    if alt:
-        # the bound slice follows the output index map: one dst block
-        in_specs.append(pl.BlockSpec((block_v,), lambda i, s, d, *_:
-                                     (d[i],)))
-        operands.append(alt_lb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # sched, sched_dst, n_active, lbub
-        grid=(nt,),
-        in_specs=in_specs,
-        out_specs=(pl.BlockSpec((block_v,), lambda i, s, d, *_: (d[i],)),
-                   pl.BlockSpec((block_v,), lambda i, s, d, *_: (d[i],))),
-    )
-    vals, wins = pl.pallas_call(
-        functools.partial(_kernel, block_v=block_v, alt=alt),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((n_out,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_out,), jnp.int32)),
-        interpret=interpret,
-    )(*operands)
+    lbub = _lbub(lb, ub, *([prune_bound] if alt else []))
+    vals, wins = _relax_tiles(
+        dsrc, None, src_local, dst_local, w, sched, jnp.zeros_like(sched),
+        tile_dst[sched], sched_n, lbub, alt_lb,
+        src_block=dist_block.shape[0], block_v=block_v, tile_e=tile_e,
+        n_out=n_dst_blocks * block_v, interpret=interpret)
     # destination blocks without any tile are never visited by the grid:
     # mask their (uninitialized) output range to the no-candidate value
     visited = jnp.repeat(bucket_nonempty, block_v)
@@ -264,10 +357,15 @@ def edge_relax(dist_block, frontier_block, src_local, dst_local, w,
 # multi-round fused megakernel
 # ---------------------------------------------------------------------------
 
-# counter slots of the fused kernels' in-kernel metric accumulator
-FUSED_COUNTERS = ("n_trav", "n_relax", "n_updates", "n_extended",
-                  "n_rounds", "n_tiles", "n_exec", "n_pruned")
-PARTIAL_COUNTERS = ("n_trav", "n_relax", "n_tiles", "n_pruned")
+
+
+FUSED_ON_TPU = (
+    "the fused multi-round megakernel (fused_rounds > 0 on the "
+    "single-device blocked backend) does not compile for TPU: it keeps the "
+    "whole edge slab and the O(V) state in VMEM at once, gathers by vector "
+    "index and compacts its schedule with an nt x nt plane, none of which "
+    "Mosaic lowers; use fused_rounds=0 (the gridded edge_relax kernel) or "
+    "use_kernel=False for the jnp reference")
 
 
 def _tile_pass(dist_src, paths_src, parent_src, src, dst, w, tdst, tfirst,
@@ -433,7 +531,7 @@ def edge_relax_fused(dist, parent, frontier, deg, src, dst, w, tile_dst,
                      prune_infl=None, prune_tgt=None, *,
                      block_v: int = DEFAULT_BLOCK_V,
                      tile_e: int = DEFAULT_TILE_E, fused_rounds: int = 4,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """Run up to ``fused_rounds`` relaxation rounds in one invocation.
 
     ``dist``/``parent``/``frontier``/``deg`` span the padded vertex range
@@ -447,6 +545,8 @@ def edge_relax_fused(dist, parent, frontier, deg, src, dst, w, tile_dst,
     round; ``counts`` is the int32 ``FUSED_COUNTERS`` vector summed over
     executed rounds.
     """
+    if not resolve_interpret(interpret):
+        raise ConfigError(FUSED_ON_TPU)
     e = src.shape[0]
     if e % tile_e != 0 or e == 0:
         raise ValueError(f"slab length {e} is not tile-aligned "
@@ -489,84 +589,61 @@ def edge_relax_fused(dist, parent, frontier, deg, src, dst, w, tile_dst,
                    jax.ShapeDtypeStruct((n_out,), jnp.int32),
                    jax.ShapeDtypeStruct((n_out,), jnp.int32),
                    jax.ShapeDtypeStruct((8,), jnp.int32)),
-        interpret=interpret,
+        interpret=True,
     )(*prefetch, *operands)
     return dist2, parent2, front2, cnt
-
-
-def _partials_kernel(*refs, block_v: int, tile_e: int, alt: bool = False):
-    """Single-round partials over a shard's whole slab set."""
-    if alt:
-        (lbub_ref, dist_ref, paths_ref, parent_ref, src_ref, dst_ref,
-         w_ref, tdst_ref, tfirst_ref, alt_ref, val_ref, win_ref,
-         cnt_ref) = refs
-        alt_lb, bound = alt_ref[...], lbub_ref[2]
-    else:
-        (lbub_ref, dist_ref, paths_ref, parent_ref, src_ref, dst_ref,
-         w_ref, tdst_ref, tfirst_ref, val_ref, win_ref, cnt_ref) = refs
-        alt_lb, bound = None, None
-    lb = lbub_ref[0]
-    ub = lbub_ref[1]
-    val, win, trav, rlx, prn, sched_n = _tile_pass(
-        dist_ref[...], paths_ref[...], parent_ref[...], src_ref[...],
-        dst_ref[...], w_ref[...], tdst_ref[...], tfirst_ref[...], lb, ub,
-        val_ref.shape[0], block_v=block_v, tile_e=tile_e, go=jnp.int32(1),
-        alt_lb=alt_lb, prune_bound=bound)
-    val_ref[...] = val
-    win_ref[...] = win
-    cnt_ref[...] = jnp.stack([trav, rlx, sched_n, prn])
 
 
 @functools.partial(jax.jit, static_argnames=("block_v", "tile_e",
                                              "n_dst_blocks", "interpret"))
 def edge_relax_partials(dist_src, paths_src, parent_src, src, dst, w,
-                        tile_dst, tile_first, lb, ub,
+                        tile_dst, tile_first, tile_order, lb, ub,
                         alt_lb=None, prune_bound=None, *,
                         block_v: int = DEFAULT_BLOCK_V,
                         tile_e: int = DEFAULT_TILE_E, n_dst_blocks: int = 1,
-                        interpret: bool = True):
-    """One invocation of the fused tile pass in partials mode.
+                        interpret: Optional[bool] = None):
+    """One round of (min, winner) partials over a whole slab set.
 
     ``dist_src``/``paths_src``/``parent_src`` cover the slab's *local*
     source range; ``src`` ids index it directly (all of a shard's
-    source-block slabs concatenated, ids offset by their block).  Returns
-    ``(val, win, counts)``: per-destination (min, winner) partials over
-    ``n_dst_blocks * block_v`` — winners are local source ids, lift them
-    with the shard's owner-block offset — and the int32
-    ``PARTIAL_COUNTERS`` vector (``n_trav``/``n_relax``/tile count).
+    source-block slabs concatenated, ids offset by their block, so every
+    tile's sources lie in one ``block_v`` source block).  The tiles run
+    in one gridded kernel, scheduled in destination-block order across
+    the slabs so each output block is visited in one consecutive run:
+    ``tile_order`` [NT] lists the tiles sorted (stably) by ``tile_dst``,
+    a per-layout constant built with the layout
+    (:func:`~repro.core.distributed.shard_blocked`).
+    Returns ``(val, win, counts)``: per-destination (min, winner)
+    partials over ``n_dst_blocks * block_v`` — winners are local source
+    ids, lift them with the shard's owner-block offset — and the int32
+    ``PARTIAL_COUNTERS`` vector, counted in the kernel.
     """
+    interpret = resolve_interpret(interpret)
+    _check_compiled_geometry(block_v, tile_e, interpret)
     e = src.shape[0]
     if e % tile_e != 0 or e == 0:
         raise ValueError(f"slab length {e} is not tile-aligned "
                          f"(tile_e={tile_e})")
+    nb = n_dst_blocks
+    touched = (paths_src[src] > 0) & jnp.isfinite(w)
+    active = _active_tiles(touched, tile_first, tile_e)
+    sched, sched_n = _compact(active, tile_order)
+    sched_dst = tile_dst[sched]
+    # padding slots carry their slab's first source id, so a tile's first
+    # slot names its source block
+    sched_src = src[sched * tile_e] // block_v
+    dsrc = jnp.where(paths_src > 0, dist_src, jnp.inf)
     alt = alt_lb is not None
-    scal = [jnp.float32(lb), jnp.float32(ub)]
-    if alt:
-        scal.append(jnp.float32(prune_bound))
-    lbub = jnp.stack(scal)
-    n_out = n_dst_blocks * block_v
-    n_src = dist_src.shape[0]
-    nt = e // tile_e
-    whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,))
-    in_specs = ([whole((n_src,))] * 3 + [whole((e,))] * 3
-                + [whole((nt,))] * 2)
-    operands = [dist_src, paths_src.astype(jnp.int32), parent_src, src,
-                dst, w, tile_dst, tile_first.astype(jnp.int32)]
-    if alt:
-        in_specs.append(whole((n_out,)))
-        operands.append(alt_lb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,      # lbub
-        grid=(1,),
-        in_specs=in_specs,
-        out_specs=(whole((n_out,)), whole((n_out,)), whole((4,))),
-    )
-    return pl.pallas_call(
-        functools.partial(_partials_kernel, block_v=block_v, tile_e=tile_e,
-                          alt=alt),
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((n_out,), jnp.float32),
-                   jax.ShapeDtypeStruct((n_out,), jnp.int32),
-                   jax.ShapeDtypeStruct((4,), jnp.int32)),
-        interpret=interpret,
-    )(lbub, *operands)
+    lbub = _lbub(lb, ub, *([prune_bound] if alt else []))
+    val, win, cnt = _relax_tiles(
+        dsrc, parent_src, src, dst, w, sched, sched_src, sched_dst, sched_n,
+        lbub, alt_lb, src_block=block_v, block_v=block_v, tile_e=tile_e,
+        n_out=nb * block_v, interpret=interpret)
+    # only the scheduled tiles' destination blocks were written; the
+    # forced first tiles cover every block that has edges
+    step = jnp.arange(sched.shape[0], dtype=jnp.int32)
+    visited = jnp.zeros((nb,), bool).at[
+        jnp.where(step < sched_n, sched_dst, nb)].set(True, mode="drop")
+    visited = jnp.repeat(visited, block_v)
+    return (jnp.where(visited, val, jnp.inf),
+            jnp.where(visited, win, INT_MAX), cnt.at[2].set(sched_n))

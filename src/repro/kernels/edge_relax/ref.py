@@ -1,8 +1,8 @@
 """Pure-jnp oracles for the edge_relax kernels.
 
 These are the validation twins of the Pallas kernels AND the arrays-only
-fallbacks that actually run where the kernels cannot (``use_kernel=False``
-— e.g. under interpret-mode ``shard_map``); every reduction resolves ties
+reference path a caller selects by name (``use_kernel=False``); every
+reduction resolves ties
 exactly like the kernels (min value, then min source id), so the two
 paths are bitwise-interchangeable.
 """
@@ -11,12 +11,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .edge_relax import INT_MAX
+from .edge_relax import DEFAULT_BLOCK_V, DEFAULT_TILE_E, INT_MAX
 
 
 def edge_relax_ref(dist_block, frontier_block, src_local, dst_local, w,
                    lb, ub, alt_lb=None, prune_bound=None, *,
-                   block_v: int = 512, n_dst_blocks: int = 1):
+                   block_v: int = DEFAULT_BLOCK_V, n_dst_blocks: int = 1):
     """Returns ``(vals, winners)`` matching the Pallas kernel contract:
     per-destination min candidate plus the smallest block-local source id
     achieving it (INT_MAX where no in-window candidate exists).  With
@@ -61,7 +61,8 @@ def _slab_counters(pa_src, w, dst, p_src, ok, tile_first, tile_e: int,
 def edge_relax_fused_ref(dist, parent, frontier, deg, src, dst, w,
                          tile_dst, tile_first, lb, ub, alt_lb=None,
                          prune_ub=None, prune_infl=None, prune_tgt=None, *,
-                         block_v: int = 512, tile_e: int = 512,
+                         block_v: int = DEFAULT_BLOCK_V,
+                         tile_e: int = DEFAULT_TILE_E,
                          fused_rounds: int = 4):
     """Arrays-only twin of :func:`..edge_relax.edge_relax_fused`.
 
@@ -121,8 +122,9 @@ def edge_relax_fused_ref(dist, parent, frontier, deg, src, dst, w,
 
 def edge_relax_partials_ref(dist_src, paths_src, parent_src, src, dst, w,
                             tile_dst, tile_first, lb, ub, alt_lb=None,
-                            prune_bound=None, *, block_v: int = 512,
-                            tile_e: int = 512, n_dst_blocks: int = 1):
+                            prune_bound=None, *, block_v: int = DEFAULT_BLOCK_V,
+                            tile_e: int = DEFAULT_TILE_E,
+                            n_dst_blocks: int = 1):
     """Arrays-only twin of :func:`..edge_relax.edge_relax_partials`:
     one-shot (min, winner) partials over a whole slab set plus the
     ``PARTIAL_COUNTERS`` vector."""

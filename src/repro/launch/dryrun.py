@@ -123,7 +123,6 @@ def run_sssp(mesh_kind: str, scale: int = 26, edge_factor: int = 16,
              out_dir: str = ART_DIR):
     """Dry-run the distributed SSSP engine on a Graph500-scale struct."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core import distributed as dist
     from repro.core import stepping
     from repro.core.graph import RATIO_NUM
@@ -174,9 +173,9 @@ def run_sssp(mesh_kind: str, scale: int = 26, edge_factor: int = 16,
             body = dist._v2_body(n, block, axes, params, 1 << 20, 0,
                                  tuple(mesh.shape[a] for a in axes))
             out_specs = (P(axes), P(axes), P())
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(dist.graph_specs(axes), P(), P()),
-                       out_specs=out_specs, check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(dist.graph_specs(axes), P(), P()),
+                           out_specs=out_specs, check_vma=False)
         lowered = jax.jit(fn).lower(sg, src_s, gp_s)
         compiled = lowered.compile()
         cost = compiled.cost_analysis() or {}

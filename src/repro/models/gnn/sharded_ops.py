@@ -23,7 +23,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def _nshards(mesh, axes):
@@ -44,9 +43,9 @@ def gather0(ctx, table, idx):
         return full[ix]
 
     spec2 = P(axes, *([None] * (table.ndim - 1)))
-    return shard_map(body, mesh=mesh, in_specs=(spec2, P(axes)),
-                     out_specs=P(axes, *([None] * (table.ndim - 1))),
-                     check_rep=False)(table, idx)
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec2, P(axes)),
+                         out_specs=P(axes, *([None] * (table.ndim - 1))),
+                         check_vma=False)(table, idx)
 
 
 def scatter_sum0(ctx, values, idx, n):
@@ -60,11 +59,11 @@ def scatter_sum0(ctx, values, idx, n):
         return jax.lax.psum_scatter(full, axes, scatter_dimension=0,
                                     tiled=True)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axes, *([None] * (values.ndim - 1))),
-                               P(axes)),
-                     out_specs=P(axes, *([None] * (values.ndim - 1))),
-                     check_rep=False)(values, idx)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axes, *([None] * (values.ndim - 1))),
+                                   P(axes)),
+                         out_specs=P(axes, *([None] * (values.ndim - 1))),
+                         check_vma=False)(values, idx)
 
 
 def _scatter_extreme(ctx, values, idx, n, kind):
@@ -89,11 +88,11 @@ def _scatter_extreme(ctx, values, idx, n, kind):
                     else jnp.min(recv, axis=0))
         return part
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(axes, *([None] * (values.ndim - 1))),
-                               P(axes)),
-                     out_specs=P(axes, *([None] * (values.ndim - 1))),
-                     check_rep=False)(values, idx)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(axes, *([None] * (values.ndim - 1))),
+                                   P(axes)),
+                         out_specs=P(axes, *([None] * (values.ndim - 1))),
+                         check_vma=False)(values, idx)
 
 
 def scatter_max0(ctx, values, idx, n):

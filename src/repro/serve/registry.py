@@ -57,9 +57,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from ..core import landmarks as landmarks_mod
 from ..core import relax
 from ..core.config import EngineConfig, resolve_devices
-from ..core.distributed import (blocked_specs, graph_specs, shard_blocked,
-                                shard_graph, sssp_distributed_batch,
-                                ShardedGraph)
+from ..core.distributed import (blocked_specs, graph_specs, place_on_mesh,
+                                shard_blocked, shard_graph,
+                                sssp_distributed_batch)
 from ..core.graph import DeviceGraph, HostGraph
 from ..core.landmarks import LandmarkSet, build_landmarks, hop_bfs
 from ..core.sssp import GOALS, repair_relax, sssp_batch
@@ -321,16 +321,12 @@ class ShardedGraphEngine(_EngineBase):
         self.mesh = Mesh(np.array(devs), ("graph",))
         sg = shard_graph(hg, len(devs))
         # pre-place each slab on its owner device (the engine's layout)
-        self.sg = ShardedGraph(*(
-            jax.device_put(x, NamedSharding(self.mesh, s))
-            for x, s in zip(sg, graph_specs("graph"))))
+        self.sg = place_on_mesh(sg, self.mesh, graph_specs("graph"))
         self.blocked = None
         if self.backend == "blocked":
             arrays, bmeta = shard_blocked(hg, len(devs), **blocked_opts)
-            arrays = type(arrays)(*(
-                jax.device_put(x, NamedSharding(self.mesh, s))
-                for x, s in zip(arrays, blocked_specs("graph"))))
-            self.blocked = (arrays, bmeta)
+            self.blocked = (place_on_mesh(arrays, self.mesh,
+                                          blocked_specs("graph")), bmeta)
         if landmarks is not None:
             # the ALT matrix is replicated across the mesh: every shard
             # prunes with the full per-vertex bound vector
@@ -1048,9 +1044,7 @@ class GraphRegistry:
                              if keep_lm else None)
         if eng.tier == "sharded":
             sg = patch_sharded_with(eng.sg, new_host, applied)
-            eng.sg = ShardedGraph(*(
-                jax.device_put(x, NamedSharding(eng.mesh, s))
-                for x, s in zip(sg, graph_specs("graph"))))
+            eng.sg = place_on_mesh(sg, eng.mesh, graph_specs("graph"))
             if eng.blocked is not None:
                 # per-shard blocked slabs: full re-bucket for now (the
                 # uniform-n_tiles stacked layout couples every shard's
@@ -1060,10 +1054,8 @@ class GraphRegistry:
                     new_host, len(eng.devices), block_v=bmeta.block_v,
                     tile_e=bmeta.tile_e, use_kernel=bmeta.use_kernel,
                     interpret=bmeta.interpret)
-                arrays = type(arrays)(*(
-                    jax.device_put(x, NamedSharding(eng.mesh, s))
-                    for x, s in zip(arrays, blocked_specs("graph"))))
-                eng.blocked = (arrays, bmeta)
+                eng.blocked = (place_on_mesh(arrays, eng.mesh,
+                                             blocked_specs("graph")), bmeta)
             return eng
         g = new_host.to_device()
         if eng.device is not None:

@@ -149,3 +149,34 @@ def test_slice_for_shard_uniform_tiles():
         for slab in bg.slabs:
             assert slab.tile_dst.shape == (32,)
             assert slab.w.shape == (32 * 64,)
+
+
+def test_layout_bytes_reckoned_before_allocating():
+    """The padded size comes from the bucket counts alone; over the limit
+    every layout function raises ConfigError with the bytes, before any
+    slab."""
+    from repro.core.config import ConfigError
+    from repro.core.distributed import shard_blocked, shard_layout_bytes
+    from repro.core.graph import (SLOT_BYTES, TILE_INDEX_BYTES,
+                                  blocked_layout_bytes)
+    g = kronecker(9, 8, seed=4)
+    kw = dict(block_v=64, tile_e=64)
+    bg = build_blocked(g, **kw)
+    slots = sum(int(s.w.shape[0]) for s in bg.slabs)
+    tiles = sum(int(s.tile_dst.shape[0]) for s in bg.slabs)
+    want = slots * SLOT_BYTES + tiles * TILE_INDEX_BYTES
+    assert blocked_layout_bytes(g, **kw) == want
+    assert build_blocked(g, max_bytes=want, **kw).n_blocks == bg.n_blocks
+    with pytest.raises(ConfigError, match=f"needs {want} bytes"):
+        build_blocked(g, max_bytes=want - 1, **kw)
+    with pytest.raises(ConfigError, match="bucket_edges"):
+        bucket_edges(g.src[:10] % 64, g.dst[:10], g.w[:10],
+                     n_dst_blocks=8, block_v=64, tile_e=64, max_bytes=100)
+    with pytest.raises(ConfigError, match="slice_for_shard"):
+        slice_for_shard(g, 1, 4, max_bytes=1000, **kw)
+    arrays, _ = shard_blocked(g, 4, **kw)
+    sb = shard_layout_bytes(g, 4, **kw)
+    assert sb == int(arrays.w.size) * SLOT_BYTES \
+        + int(arrays.tile_dst.size) * TILE_INDEX_BYTES
+    with pytest.raises(ConfigError, match=f"needs {sb} bytes"):
+        shard_blocked(g, 4, max_bytes=sb - 1, **kw)

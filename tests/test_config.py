@@ -317,3 +317,76 @@ def test_loose_blocked_backend_keeps_segment_min_sharded_tier():
     assert EngineConfig(backend="blocked_pallas",
                         shard_threshold_n=1).effective_shard_backend \
         == "blocked"
+
+
+def _on_tpu(monkeypatch):
+    """Make the platform look like a TPU to the config resolution."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def test_interpret_resolves_from_the_platform(monkeypatch):
+    from repro.core.config import resolve_interpret
+    # CPU: the Pallas interpreter, unless a caller compiles for a TPU
+    assert EngineConfig().resolve(n_devices=1).interpret is True
+    assert resolve_interpret(None) is True
+    assert resolve_interpret(False) is False
+    _on_tpu(monkeypatch)
+    assert EngineConfig().resolve(n_devices=1).interpret is False
+    assert resolve_interpret(None) is False
+    with pytest.raises(ConfigError, match="interpreter"):
+        EngineConfig(interpret=True).resolve(n_devices=1)
+
+
+def test_fused_megakernel_refused_on_tpu(monkeypatch):
+    cfg = EngineConfig(backend="blocked_pallas", fused_rounds=4)
+    assert cfg.resolve(n_devices=1).fused_rounds == 4      # interpreted
+    _on_tpu(monkeypatch)
+    with pytest.raises(ConfigError, match="does not compile for TPU"):
+        cfg.resolve(n_devices=1)
+    # the jnp reference stays available by name; the sharded tier's
+    # bucket-fusion waves run the gridded partials kernel
+    assert EngineConfig(backend="blocked_pallas", fused_rounds=4,
+                        use_kernel=False).resolve(n_devices=1)
+    assert EngineConfig(tier="sharded", shard_backend="blocked",
+                        fused_rounds=4).resolve(n_devices=2)
+
+
+def test_compile_cache_follows_env_else_fixed_checkout_dir(monkeypatch):
+    import jax
+    from repro import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/somewhere/else")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable_compile_cache() == "/somewhere/else"
+        # JAX reads the variable itself; nothing is set in code
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(compile_cache.ENV)
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path   # fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_kernel_geometry_refused_at_resolve_on_tpu(monkeypatch):
+    """Compiled kernels take block_v/tile_e in multiples of 1024; the
+    config layer refuses other sizes before any layout is built."""
+    small = EngineConfig(backend="blocked_pallas", block_v=256, tile_e=512)
+    assert small.resolve(n_devices=1).interpret is True     # interpreted
+    _on_tpu(monkeypatch)
+    with pytest.raises(ConfigError, match="multiples of 1024"):
+        small.resolve(n_devices=1)
+    with pytest.raises(ConfigError, match="multiples of 1024"):
+        EngineConfig(tier="sharded", shard_backend="blocked",
+                     tile_e=512).resolve(n_devices=4)
+    assert EngineConfig(backend="blocked_pallas", block_v=256, tile_e=512,
+                        use_kernel=False).resolve(n_devices=1)
+    assert EngineConfig(backend="blocked_pallas").resolve(n_devices=1)
+    # the sharded layout snaps block_v to a divisor of the owner block
+    sharded = EngineConfig(tier="sharded", shard_backend="blocked")
+    assert sharded.resolve(n=4 * 4096, n_devices=4)
+    with pytest.raises(ConfigError, match="snaps block_v=1024"):
+        sharded.resolve(n=4 * 3000, n_devices=4)

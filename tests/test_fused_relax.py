@@ -174,7 +174,8 @@ def test_partials_kernel_matches_ref():
     for use_kernel in (True, False):
         out[use_kernel] = ops.relax_partials(
             dist, paths, parent, fs.src, fs.dst, fs.w, fs.tile_dst,
-            fs.tile_first, lb, ub, block_v=bg.block_v, tile_e=bg.tile_e,
+            fs.tile_first, jnp.argsort(fs.tile_dst, stable=True), lb, ub,
+            block_v=bg.block_v, tile_e=bg.tile_e,
             n_dst_blocks=bg.n_dst_blocks, use_kernel=use_kernel)
     for a, b, what in zip(out[True], out[False],
                           ("best", "winner", "counters")):

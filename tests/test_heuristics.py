@@ -120,8 +120,10 @@ def test_gap_positive_on_degenerate_luts(sd, hd, mult):
                                            mult=jnp.float32(mult)))
         # both inherit the >= max(w_floor, 1e-12) clamp: a shrunken
         # adaptive window can never hit zero and stall the solve loop
-        assert g0 >= 1e-12, (lut[:3], sd, hd)
-        assert ga >= 1e-12, (lut[:3], sd, hd, mult)
+        # (the clamp is f32, and float32(1e-12) lies just below 1e-12)
+        floor = np.float32(1e-12)
+        assert g0 >= floor, (lut[:3], sd, hd)
+        assert ga >= floor, (lut[:3], sd, hd, mult)
 
 
 def test_gap_mult_one_matches_static(graph):
@@ -142,14 +144,17 @@ def test_adaptive_update_clamps_and_snapshots():
     """Feedback clamps hold under extreme counters, and the counter
     snapshots always advance to the observed values."""
     pol = stepping.DEFAULT_ADAPTIVE
+    # the clamps hold in f32: compare with the bounds rounded to float32
+    # (float32(0.995) lies just above 0.995)
+    f32 = np.float32
     ps = stepping.policy_init(stepping.SteppingParams())
     # hammer the "too wide" signal: mult must stop at mult_min
     for r in range(1, 30):
         ps = stepping.adaptive_update(ps, jnp.int32(100 * r),
                                       jnp.int32(1000 * r), jnp.int32(0))
     assert float(ps.mult) == pytest.approx(pol.mult_min)
-    assert float(ps.alpha) >= pol.alpha_min
-    assert float(ps.beta) >= pol.beta_min
+    assert float(ps.alpha) >= f32(pol.alpha_min)
+    assert float(ps.beta) >= f32(pol.beta_min)
     assert int(ps.last_rounds) == 100 * 29
     # hammer "too narrow": mult must stop at mult_max
     ps2 = stepping.policy_init(stepping.SteppingParams())
@@ -157,8 +162,8 @@ def test_adaptive_update_clamps_and_snapshots():
         ps2 = stepping.adaptive_update(ps2, jnp.int32(r),
                                        jnp.int32(10 * r), jnp.int32(10 * r))
     assert float(ps2.mult) == pytest.approx(pol.mult_max)
-    assert float(ps2.alpha) <= pol.alpha_max
-    assert float(ps2.beta) <= pol.beta_max
+    assert float(ps2.alpha) <= f32(pol.alpha_max)
+    assert float(ps2.beta) <= f32(pol.beta_max)
 
 
 def test_compute_st_within_bounds(graph):
