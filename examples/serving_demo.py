@@ -14,10 +14,13 @@ load (p2p / bounded / k-nearest / tree) through the router, and prints
 per-kind samples plus placement and serving counters.
 
 At exit it prints the serving plane's metrics snapshot (the one
-registry/scheduler/router ``MetricsRegistry``), then runs one *traced*
-solve on the hottest graph and writes its per-round solve trace as a
-Perfetto/Chrome-trace JSON (``--trace-out``, default
-``serving_demo_trace.json`` — load it at https://ui.perfetto.dev).
+registry/scheduler/router ``MetricsRegistry``), then solves one tree on
+the hottest graph under a ``jax.profiler.trace()`` capture
+(``--trace-dir``, default ``serving_demo_trace/``; open its
+``perfetto_trace.json.gz`` at https://ui.perfetto.dev, or the directory
+in TensorBoard).  The device ops carry the solve's named phases
+(``sssp.round``, ``round.gather``, ..., ``transition.pull``) in their
+HLO ``op_name``, and ``Solver.phase_table`` maps each op to its phase.
 """
 import argparse
 import os
@@ -42,8 +45,8 @@ def main():
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--rate-qps", type=float, default=None,
                     help="open-loop arrival pacing (default: closed loop)")
-    ap.add_argument("--trace-out", default="serving_demo_trace.json",
-                    help="write a traced solve's Perfetto JSON here")
+    ap.add_argument("--trace-dir", default="serving_demo_trace",
+                    help="write a profiled solve's capture here")
     args = ap.parse_args()
 
     n = 1 << args.scale
@@ -140,17 +143,26 @@ def main():
         elif entry["value"]:
             print(f"  {name}: {entry['value']}")
 
-    # one traced solve on the hottest graph -> Perfetto JSON of its
-    # per-round stepping behavior (solve/step/round/invocation tracks)
+    # one tree on the hottest graph under a profiler capture: its device
+    # ops are named by the solve's phases (solve -> round / transition)
+    import collections  # noqa: E402
+
+    import jax  # noqa: E402
     from repro.api import Solver, SolveSpec  # noqa: E402
-    from repro.obs import write_perfetto  # noqa: E402
 
     hot = max(shares, key=shares.get)
-    with Solver.open(graphs[hot], EngineConfig(trace=True)) as solver:
-        res = solver.solve(SolveSpec.tree(0))
-    write_perfetto(res.trace, args.trace_out, name=f"sssp:{hot}")
-    print(f"\ntraced solve on {hot!r}: {res.trace.n_records} rounds, "
-          f"{int(res.metrics.n_relax)} relaxations -> {args.trace_out}")
+    spec = SolveSpec.tree(0)
+    with Solver.open(graphs[hot]) as solver:
+        solver.solve(spec).block_until_ready()    # compile outside it
+        with jax.profiler.trace(args.trace_dir, create_perfetto_trace=True):
+            res = solver.solve(spec).block_until_ready()
+        table = solver.phase_table(spec)
+    by_phase = collections.Counter(table.values())
+    print(f"\nprofiled solve on {hot!r}: {int(res.metrics.n_rounds)} "
+          f"rounds, {int(res.metrics.n_relax)} relaxations -> "
+          f"{args.trace_dir}/")
+    print("  compiled instructions by phase: " + ", ".join(
+        f"{p}={by_phase[p]}" for p in sorted(by_phase)))
 
 
 if __name__ == "__main__":

@@ -55,7 +55,8 @@ from .core import relax
 from .core.config import (ConfigError, EngineConfig, ResolvedEngine,
                           as_resolved)
 from .core.graph import BlockedGraph, DeviceGraph, HostGraph
-from .core.sssp import GOALS, normalized_metrics, sssp, sssp_batch
+from .core.sssp import (GOALS, compiled_text, normalized_metrics, sssp,
+                        sssp_batch)
 from .obs import profiling
 
 __all__ = ["EngineConfig", "ConfigError", "SolveSpec", "SolveResult",
@@ -616,6 +617,25 @@ class Solver:
         return SolveResult(spec=spec, dist=dist, parent=parent,
                            metrics=metrics, deg=self.deg, tier=self.tier,
                            trace=trace)
+
+    def phase_table(self, spec: SolveSpec) -> profiling.PhaseTable:
+        """``{instruction_name: phase}`` of the program that solving
+        ``spec`` runs (:func:`repro.obs.profiling.phase_table`): the
+        join from a profiler trace's op events to the solve's named
+        phases.  The program is compiled, or found in the compile
+        cache, and not run.  Single tier only."""
+        if self.tier != "single":
+            raise ConfigError(f"phase tables cover the single tier; this "
+                              f"session is {self.tier!r}")
+        if self._closed:
+            raise RuntimeError("solver is closed")
+        spec.check_bounds(self.n)
+        srcs = list(spec.sources) if spec.batched else spec.sources
+        text = compiled_text(self._dg, srcs, batched=spec.batched,
+                             config=self.resolved, layout=self._layout,
+                             landmarks=self._landmarks,
+                             **self._goal_args(spec))
+        return profiling.phase_table(text)
 
     def _solve_sharded(self, spec: SolveSpec) -> SolveResult:
         from .core.distributed import (sssp_distributed,

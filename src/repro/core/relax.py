@@ -53,6 +53,7 @@ import jax.numpy as jnp
 
 from .graph import DeviceGraph, BlockedGraph, build_blocked
 from ..kernels.edge_relax.ops import relax_bucket, relax_fused, relax_partials
+from ..obs import profiling
 
 INT_MAX = jnp.iinfo(jnp.int32).max
 INF = jnp.float32(jnp.inf)
@@ -266,8 +267,6 @@ def register_backend(backend: RelaxBackend, aliases=()) -> RelaxBackend:
     # annotate layout builds at the source: every prepare() — from the
     # facade, the serving registry, or direct engine calls — shows up as
     # one repro:relax_prepare:<name> span in jax.profiler captures
-    from ..obs import profiling
-
     prepare = backend.prepare
     scope = f"repro:relax_prepare:{backend.name}"
 
@@ -308,27 +307,33 @@ def _segment_min_prepare(g: DeviceGraph, **_opts) -> DeviceGraph:
 
 def _segment_min_relax(g: DeviceGraph, dist, parent, frontier, lb, ub,
                        alt_lb=None, prune_bound=None):
-    paths = leaf_pruned(frontier, dist, g.deg)
-    cand, in_window, active = edge_candidates(
-        dist[g.src], paths[g.src], parent[g.src], g.dst, g.w, lb, ub)
-    n_pruned = jnp.int32(0)
-    if alt_lb is not None:
-        active, pruned = alt_prune(cand, active, alt_lb[g.dst], prune_bound)
-        cand = jnp.where(active, cand, INF)
-        n_pruned = jnp.sum(pruned.astype(jnp.int32))
-    best, winner = segment_min_with_winner(cand, active, g.src, g.dst, g.n)
-    new_dist, new_parent, improved = apply_updates(dist, parent, best,
-                                                   winner)
-    rm = RoundMetrics(
-        improved=improved,
-        n_trav=jnp.sum(in_window.astype(jnp.int32)),
-        n_relax=jnp.sum(active.astype(jnp.int32)),
-        n_updates=jnp.sum(improved.astype(jnp.int32)),
-        n_extended=jnp.sum((improved & (g.deg > 1)).astype(jnp.int32)),
-        n_pruned=n_pruned,
-        n_tiles_scanned=jnp.float32(0),
-        n_tiles_dense=jnp.float32(0),
-        n_invocations=jnp.float32(0))
+    with profiling.phase("round.gather"):
+        paths = leaf_pruned(frontier, dist, g.deg)
+        cand, in_window, active = edge_candidates(
+            dist[g.src], paths[g.src], parent[g.src], g.dst, g.w, lb, ub)
+        pruned = None
+        if alt_lb is not None:
+            active, pruned = alt_prune(cand, active, alt_lb[g.dst],
+                                       prune_bound)
+            cand = jnp.where(active, cand, INF)
+    with profiling.phase("round.reduce"):
+        best, winner = segment_min_with_winner(cand, active, g.src, g.dst,
+                                               g.n)
+    with profiling.phase("round.apply"):
+        new_dist, new_parent, improved = apply_updates(dist, parent, best,
+                                                       winner)
+    with profiling.phase("round.count"):
+        rm = RoundMetrics(
+            improved=improved,
+            n_trav=jnp.sum(in_window.astype(jnp.int32)),
+            n_relax=jnp.sum(active.astype(jnp.int32)),
+            n_updates=jnp.sum(improved.astype(jnp.int32)),
+            n_extended=jnp.sum((improved & (g.deg > 1)).astype(jnp.int32)),
+            n_pruned=(jnp.int32(0) if pruned is None
+                      else jnp.sum(pruned.astype(jnp.int32))),
+            n_tiles_scanned=jnp.float32(0),
+            n_tiles_dense=jnp.float32(0),
+            n_invocations=jnp.float32(0))
     return new_dist, new_parent, rm
 
 

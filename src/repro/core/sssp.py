@@ -55,7 +55,7 @@ from ..obs import profiling
 from ..obs.trace import trace_append, trace_init
 
 __all__ = ["sssp", "sssp_batch", "sssp_p2p", "sssp_bounded", "sssp_knear",
-           "repair_relax",
+           "repair_relax", "compiled_text",
            "SsspMetrics", "LOGICAL_METRIC_FIELDS", "PHYSICAL_METRIC_FIELDS",
            "metrics_dict", "normalized_metrics",
            "GOALS", "goal_param_array", "INF", "INT_MAX"]
@@ -174,21 +174,22 @@ def _relax_round(backend: relax.RelaxBackend, layout, st_: SsspState,
     dispatched through the selected relaxation backend.  ``alt_lb``/
     ``prune_bound`` (p2p with landmarks) enable the ALT goal-directed cut
     inside the relaxation (see :func:`repro.core.relax.alt_prune`)."""
-    new_dist, new_parent, rm = backend.relax_window(
-        layout, st_.dist, st_.parent, st_.frontier, st_.lb, st_.ub,
-        alt_lb, prune_bound)
-    m = st_.metrics
-    metrics = m._replace(
-        n_rounds=m.n_rounds + jnp.where(jnp.any(st_.frontier), 1, 0),
-        n_extended=m.n_extended + rm.n_extended,
-        n_trav=m.n_trav + rm.n_trav,
-        n_relax=m.n_relax + rm.n_relax,
-        n_updates=m.n_updates + rm.n_updates,
-        n_pruned=m.n_pruned + rm.n_pruned,
-        n_tiles_scanned=m.n_tiles_scanned + rm.n_tiles_scanned,
-        n_tiles_dense=m.n_tiles_dense + rm.n_tiles_dense,
-        n_invocations=m.n_invocations + rm.n_invocations,
-    )
+    with profiling.phase("sssp.round"):
+        new_dist, new_parent, rm = backend.relax_window(
+            layout, st_.dist, st_.parent, st_.frontier, st_.lb, st_.ub,
+            alt_lb, prune_bound)
+        m = st_.metrics
+        metrics = m._replace(
+            n_rounds=m.n_rounds + jnp.where(jnp.any(st_.frontier), 1, 0),
+            n_extended=m.n_extended + rm.n_extended,
+            n_trav=m.n_trav + rm.n_trav,
+            n_relax=m.n_relax + rm.n_relax,
+            n_updates=m.n_updates + rm.n_updates,
+            n_pruned=m.n_pruned + rm.n_pruned,
+            n_tiles_scanned=m.n_tiles_scanned + rm.n_tiles_scanned,
+            n_tiles_dense=m.n_tiles_dense + rm.n_tiles_dense,
+            n_invocations=m.n_invocations + rm.n_invocations,
+        )
     return st_._replace(dist=new_dist, parent=new_parent,
                         frontier=rm.improved, metrics=metrics)
 
@@ -202,24 +203,25 @@ def _fused_relax_rounds(bg, fs, st_: SsspState, fused_rounds: int,
     Bitwise-identical dist/parent/frontier and logical counters; the
     kernel folds the counters into its scheduled tile pass and reports
     per-invocation sums (``FUSED_COUNTERS``)."""
-    new_dist, new_parent, new_front, cnt = relax.blocked_fused_rounds(
-        bg, fs, st_.dist, st_.parent, st_.frontier, st_.lb, st_.ub,
-        fused_rounds=fused_rounds, alt_lb=alt_lb, prune_ub=prune_ub,
-        prune_infl=prune_infl, prune_tgt=prune_tgt)
-    m = st_.metrics
-    metrics = m._replace(
-        n_rounds=m.n_rounds + cnt[4],
-        n_trav=m.n_trav + cnt[0],
-        n_relax=m.n_relax + cnt[1],
-        n_updates=m.n_updates + cnt[2],
-        n_extended=m.n_extended + cnt[3],
-        n_pruned=m.n_pruned + cnt[7],
-        n_tiles_scanned=m.n_tiles_scanned + cnt[5].astype(jnp.float32),
-        # the dense-grid comparator charges one full grid per round
-        n_tiles_dense=m.n_tiles_dense
-        + cnt[6].astype(jnp.float32) * bg.dense_grid_tiles,
-        n_invocations=m.n_invocations + jnp.float32(1),
-    )
+    with profiling.phase("sssp.round"):
+        new_dist, new_parent, new_front, cnt = relax.blocked_fused_rounds(
+            bg, fs, st_.dist, st_.parent, st_.frontier, st_.lb, st_.ub,
+            fused_rounds=fused_rounds, alt_lb=alt_lb, prune_ub=prune_ub,
+            prune_infl=prune_infl, prune_tgt=prune_tgt)
+        m = st_.metrics
+        metrics = m._replace(
+            n_rounds=m.n_rounds + cnt[4],
+            n_trav=m.n_trav + cnt[0],
+            n_relax=m.n_relax + cnt[1],
+            n_updates=m.n_updates + cnt[2],
+            n_extended=m.n_extended + cnt[3],
+            n_pruned=m.n_pruned + cnt[7],
+            n_tiles_scanned=m.n_tiles_scanned + cnt[5].astype(jnp.float32),
+            # the dense-grid comparator charges one full grid per round
+            n_tiles_dense=m.n_tiles_dense
+            + cnt[6].astype(jnp.float32) * bg.dense_grid_tiles,
+            n_invocations=m.n_invocations + jnp.float32(1),
+        )
     return st_._replace(dist=new_dist, parent=new_parent,
                         frontier=new_front, metrics=metrics)
 
@@ -232,7 +234,8 @@ def _bootstrap_ub(g: DeviceGraph, st_: SsspState,
         mask = (g.deg.astype(jnp.float32) >= high_d0) & (st_.dist > 0)
         cand = jnp.min(jnp.where(mask, st_.dist, INF))
         return jnp.minimum(ub, cand)
-    ub = jax.lax.cond(st_.lb <= 0.0, tighten, lambda ub: ub, st_.ub)
+    with profiling.phase("sssp.bootstrap"):
+        ub = jax.lax.cond(st_.lb <= 0.0, tighten, lambda ub: ub, st_.ub)
     return st_._replace(ub=ub)
 
 
@@ -284,59 +287,72 @@ def _transition(g: DeviceGraph, st_: SsspState,
     returns ``(state, ps)``.  ``ps is None`` (static policy) compiles the
     exact pre-policy program and returns the state alone.
     """
-    dist, parent = st_.dist, st_.parent
-    lb, ub = st_.lb, st_.ub
+    with profiling.phase("sssp.transition"):
+        dist, parent = st_.dist, st_.parent
+        lb, ub = st_.lb, st_.ub
 
-    # smallest pending candidate path length (>= ub); inf <=> computation done
-    pend = dist[g.src] + g.w
-    pend = jnp.where(pend >= ub, pend, INF)
-    if alt_lb is not None:
-        # a pending candidate the ALT bound would cut can never improve
-        # the goal vertex, so it neither blocks termination nor anchors
-        # the fast-forward: skipping it is exact for the p2p contract
-        bound_eff = bound_of(dist)
-        pend = jnp.where(pend + alt_lb[g.dst] > bound_eff, INF, pend)
-    min_pending = jnp.min(pend)
-    done = ~jnp.isfinite(min_pending)
+        with profiling.phase("transition.pending"):
+            # smallest pending candidate path length (>= ub); inf <=> done
+            pend = dist[g.src] + g.w
+            pend = jnp.where(pend >= ub, pend, INF)
+            if alt_lb is not None:
+                # a pending candidate the ALT bound would cut can never
+                # improve the goal vertex, so it neither blocks
+                # termination nor anchors the fast-forward: skipping it
+                # is exact for the p2p contract
+                bound_eff = bound_of(dist)
+                pend = jnp.where(pend + alt_lb[g.dst] > bound_eff, INF,
+                                 pend)
+            min_pending = jnp.min(pend)
+        done = ~jnp.isfinite(min_pending)
 
-    if ps is not None:
-        m = st_.metrics
-        ps = stepping.adaptive_update(ps, m.n_rounds, m.n_relax,
-                                      m.n_updates)
-        params = stepping.effective_params(ps)
-        mult = ps.mult
-    else:
-        mult = None
-    st_next = traversal.compute_st(dist, g.deg, g.rtow, g.n_edges2, lb, ub,
-                                   params, mult=mult)
-    lb2 = ub
-    gap2 = stepping.gap(dist, g.deg, g.rtow, g.n_edges2, lb2, params, mult)
-    ub2 = lb2 + gap2
-    # empty-window fast-forward (exact; see module docstring)
-    ffwd = (min_pending >= ub2) & ~done
-    lb2 = jnp.where(ffwd, min_pending, lb2)
-    gap3 = stepping.gap(dist, g.deg, g.rtow, g.n_edges2, lb2, params, mult)
-    ub2 = jnp.where(ffwd, lb2 + gap3, ub2)
-    st_next = jnp.minimum(st_next, lb2)
+        if ps is not None:
+            m = st_.metrics
+            ps = stepping.adaptive_update(ps, m.n_rounds, m.n_relax,
+                                          m.n_updates)
+            params = stepping.effective_params(ps)
+            mult = ps.mult
+        else:
+            mult = None
+        with profiling.phase("transition.window"):
+            st_next = traversal.compute_st(dist, g.deg, g.rtow,
+                                           g.n_edges2, lb, ub, params,
+                                           mult=mult)
+            lb2 = ub
+            gap2 = stepping.gap(dist, g.deg, g.rtow, g.n_edges2, lb2,
+                                params, mult)
+            ub2 = lb2 + gap2
+            # empty-window fast-forward (exact; see module docstring)
+            ffwd = (min_pending >= ub2) & ~done
+            lb2 = jnp.where(ffwd, min_pending, lb2)
+            gap3 = stepping.gap(dist, g.deg, g.rtow, g.n_edges2, lb2,
+                                params, mult)
+            ub2 = jnp.where(ffwd, lb2 + gap3, ub2)
+            st_next = jnp.minimum(st_next, lb2)
 
-    def with_pull(args):
-        dist, parent, metrics = args
-        return _pull_phase(g, dist, parent, st_next, lb2, ub2, metrics,
-                           alt_lb,
-                           None if alt_lb is None else bound_eff)
+        def with_pull(args):
+            dist, parent, metrics = args
+            return _pull_phase(g, dist, parent, st_next, lb2, ub2, metrics,
+                               alt_lb,
+                               None if alt_lb is None else bound_eff)
 
-    dist, parent, metrics = jax.lax.cond(
-        st_next < lb2, with_pull, lambda a: a, (dist, parent, st_.metrics))
-    # early-exit goal: the settled set only grows at step transitions, so
-    # checking here is exact — and costs one reduction per transition.
-    done = done | _goal_reached(goal, goal_param, dist, lb2)
-    frontier = relax.window_frontier(dist, st_next, lb2, ub2, g.rtow[-1])
-    frontier = frontier & ~done
-    metrics = metrics._replace(n_steps=metrics.n_steps + jnp.where(done, 0, 1))
-    out = st_._replace(dist=dist, parent=parent, frontier=frontier,
-                       lb=lb2, ub=ub2, st=st_next, done=done,
-                       metrics=metrics)
-    return out if ps is None else (out, ps)
+        with profiling.phase("transition.pull"):
+            dist, parent, metrics = jax.lax.cond(
+                st_next < lb2, with_pull, lambda a: a,
+                (dist, parent, st_.metrics))
+        # early-exit goal: the settled set only grows at step
+        # transitions, so checking here is exact — and costs one
+        # reduction per transition.
+        done = done | _goal_reached(goal, goal_param, dist, lb2)
+        frontier = relax.window_frontier(dist, st_next, lb2, ub2,
+                                         g.rtow[-1])
+        frontier = frontier & ~done
+        metrics = metrics._replace(
+            n_steps=metrics.n_steps + jnp.where(done, 0, 1))
+        out = st_._replace(dist=dist, parent=parent, frontier=frontier,
+                           lb=lb2, ub=ub2, st=st_next, done=done,
+                           metrics=metrics)
+        return out if ps is None else (out, ps)
 
 
 def _trace_record(s0: SsspState, s1: SsspState, buf):
@@ -752,6 +768,21 @@ def sssp(g: DeviceGraph, source, *, backend=None, layout=None,
     goal-directed pruning for p2p goals; with ``use_alt=True`` in the
     config and no explicit set, one is built on the fly.
     """
+    args, tc = _sssp_args(
+        g, source, backend=backend, layout=layout, max_iters=max_iters,
+        alpha=alpha, beta=beta, fused_rounds=fused_rounds, policy=policy,
+        goal=goal, goal_param=goal_param, config=config,
+        landmarks=landmarks, **backend_opts)
+    with profiling.annotate("repro:sssp_dispatch"):
+        out = _sssp_jit(*args)
+    return out if tc > 0 else out[:3]
+
+
+def _sssp_args(g: DeviceGraph, source, *, backend=None, layout=None,
+               max_iters=None, alpha=None, beta=None, fused_rounds=None,
+               policy=None, goal: str = "tree", goal_param=None,
+               config=None, landmarks=None, **backend_opts):
+    """``(args, trace_capacity)``: what :func:`sssp` passes ``_sssp_jit``."""
     be, max_iters, alpha, beta, fr, tc, pol, opts, r = _engine_args(
         g, config, backend, max_iters, alpha, beta, fused_rounds, policy,
         backend_opts)
@@ -760,10 +791,8 @@ def sssp(g: DeviceGraph, source, *, backend=None, layout=None,
     gp = goal_param_array(goal, goal_param)
     _check_goal_bounds(goal, gp, g.n)
     alt_data = _resolve_alt(g, landmarks, r, goal)
-    with profiling.annotate("repro:sssp_dispatch"):
-        out = _sssp_jit(g, layout, jnp.int32(source), be, max_iters, alpha,
-                        beta, goal, gp, fr, tc, pol, alt_data, r.p2p_mode)
-    return out if tc > 0 else out[:3]
+    return (g, layout, jnp.int32(source), be, max_iters, alpha, beta, goal,
+            gp, fr, tc, pol, alt_data, r.p2p_mode), tc
 
 
 def _shim(name: str, replacement: str) -> None:
@@ -816,6 +845,23 @@ def sssp_batch(g: DeviceGraph, sources, *, backend=None,
     batch-stacked trace ring when the config enables tracing, as in
     :func:`sssp`).
     """
+    args, tc = _sssp_batch_args(
+        g, sources, backend=backend, layout=layout, max_iters=max_iters,
+        alpha=alpha, beta=beta, fused_rounds=fused_rounds, policy=policy,
+        goal=goal, goal_params=goal_params, config=config,
+        landmarks=landmarks, **backend_opts)
+    with profiling.annotate("repro:sssp_batch_dispatch"):
+        out = _sssp_batch_jit(*args)
+    return out if tc > 0 else out[:3]
+
+
+def _sssp_batch_args(g: DeviceGraph, sources, *, backend=None, layout=None,
+                     max_iters=None, alpha=None, beta=None,
+                     fused_rounds=None, policy=None, goal: str = "tree",
+                     goal_params=None, config=None, landmarks=None,
+                     **backend_opts):
+    """``(args, trace_capacity)``: what :func:`sssp_batch` passes
+    ``_sssp_batch_jit``."""
     be, max_iters, alpha, beta, fr, tc, pol, opts, r = _engine_args(
         g, config, backend, max_iters, alpha, beta, fused_rounds, policy,
         backend_opts)
@@ -830,11 +876,19 @@ def sssp_batch(g: DeviceGraph, sources, *, backend=None,
                          f"{sources.shape}")
     _check_goal_bounds(goal, gp, g.n)
     alt_data = _resolve_alt(g, landmarks, r, goal)
-    with profiling.annotate("repro:sssp_batch_dispatch"):
-        out = _sssp_batch_jit(g, layout, sources, be, max_iters, alpha,
-                              beta, goal, gp, fr, tc, pol, alt_data,
-                              r.p2p_mode)
-    return out if tc > 0 else out[:3]
+    return (g, layout, sources, be, max_iters, alpha, beta, goal, gp, fr,
+            tc, pol, alt_data, r.p2p_mode), tc
+
+
+def compiled_text(g: DeviceGraph, sources, *, batched: bool = False,
+                  **kw) -> str:
+    """The optimized HLO text of the program that :func:`sssp` (or,
+    ``batched``, :func:`sssp_batch`) runs with these arguments; it is
+    compiled, or found in the compile cache, and not run."""
+    fn, make = ((_sssp_batch_jit, _sssp_batch_args) if batched
+                else (_sssp_jit, _sssp_args))
+    args, _ = make(g, sources, **kw)
+    return fn.lower(*args).compile().as_text()
 
 
 def metrics_dict(metrics: SsspMetrics) -> dict:

@@ -1,17 +1,16 @@
-"""Exporters: Prometheus text exposition, JSONL snapshots, Perfetto traces.
+"""Exporters: Prometheus text exposition and JSONL snapshots.
 
-Three consumers, three formats, all derived from the same two sources of
-truth (a :meth:`MetricsRegistry.snapshot` dict and a
-:class:`~repro.obs.trace.SolveTrace`):
+Two consumers, two formats, both derived from a
+:meth:`MetricsRegistry.snapshot` dict:
 
 * :func:`to_prometheus` — the Prometheus/OpenMetrics text exposition
   (``# HELP`` / ``# TYPE`` headers, ``_bucket``/``_sum``/``_count``
   histogram series) for scrape endpoints;
 * :func:`write_jsonl_snapshot` — append-only JSONL dumps for offline
-  perf-trajectory analysis (one snapshot per line);
-* :func:`trace_to_perfetto` — a Chrome-trace (Perfetto JSON) view of a
-  solve trace: a ``solve`` span over ``step`` (stepping-window) spans
-  over ``round`` spans with per-round counters attached as args.
+  perf-trajectory analysis (one snapshot per line).
+
+A solve's timeline is a ``jax.profiler.trace()`` capture, whose device
+ops carry the solve's named phases (:mod:`repro.obs.profiling`).
 
 :func:`parse_prometheus` is a deliberately strict mini-parser used by
 tests and the CI smoke step to prove the exposition is well-formed —
@@ -24,12 +23,7 @@ import math
 import re
 import time
 
-from .trace import SolveTrace, TRACE_COLUMNS
-
-__all__ = [
-    "to_prometheus", "parse_prometheus", "write_jsonl_snapshot",
-    "trace_to_perfetto", "write_perfetto",
-]
+__all__ = ["to_prometheus", "parse_prometheus", "write_jsonl_snapshot"]
 
 _SAMPLE_RE = re.compile(
     r"^(?P<name>[a-zA-Z_:][a-zA-Z0-9_:]*)"
@@ -125,88 +119,3 @@ def write_jsonl_snapshot(snapshot: dict, path, meta: dict = None) -> None:
     record = {"ts": time.time(), **(meta or {}), "metrics": snapshot}
     with open(path, "a") as f:
         f.write(json.dumps(record) + "\n")
-
-
-# ---------------------------------------------------------------------------
-# Perfetto / Chrome-trace export
-# ---------------------------------------------------------------------------
-
-# Track (tid) layout inside the exported process: one lane per nesting
-# level so the solve -> step -> round -> invocation hierarchy renders as
-# stacked tracks even in viewers that don't nest same-tid spans.
-_TID_SOLVE, _TID_STEP, _TID_ROUND, _TID_INVOKE = 0, 1, 2, 3
-
-
-def trace_to_perfetto(trace: SolveTrace, name: str = "solve",
-                      pid: int = 0) -> dict:
-    """A :class:`SolveTrace` as a Chrome-trace (Perfetto-loadable) dict.
-
-    Solve traces carry no wall-clock — rounds execute inside one
-    compiled ``while_loop`` — so the timeline uses *logical work time*:
-    each round span lasts ``max(n_trav + n_pull_trav + n_relax, 1)``
-    microseconds.  Span widths are therefore proportional to relaxation
-    work, which is exactly the view the stepping-policy analysis needs
-    (a mis-sized window shows up as one giant round span).
-    """
-    cols = trace.columns
-    events = [
-        {"ph": "M", "pid": pid, "tid": tid, "name": "thread_name",
-         "args": {"name": lane}}
-        for tid, lane in ((_TID_SOLVE, "solve"), (_TID_STEP, "steps"),
-                          (_TID_ROUND, "rounds"),
-                          (_TID_INVOKE, "invocations"))
-    ]
-    t = 0
-    step_idx, step_t0 = 0, 0
-    for i in range(trace.n_records):
-        rec = {c: cols[c][i].item() for c in TRACE_COLUMNS}
-        work = int(rec["n_trav"] + rec["n_pull_trav"] + rec["n_relax"])
-        dur = max(work, 1)
-        rounds = int(rec["n_rounds"])
-        rname = (f"round {int(rec['iter'])}" if rounds <= 1
-                 else f"rounds x{rounds} (iter {int(rec['iter'])})")
-        events.append({
-            "ph": "X", "pid": pid, "tid": _TID_ROUND, "name": rname,
-            "ts": t, "dur": dur, "cat": "round", "args": rec,
-        })
-        if rec["n_invocations"] > 0:
-            events.append({
-                "ph": "X", "pid": pid, "tid": _TID_INVOKE,
-                "name": f"invoke x{int(rec['n_invocations'])}",
-                "ts": t, "dur": dur, "cat": "invocation",
-                "args": {"n_tiles_scanned": rec["n_tiles_scanned"],
-                         "n_tiles_dense": rec["n_tiles_dense"]},
-            })
-        t += dur
-        if rec["stepped"]:
-            events.append({
-                "ph": "X", "pid": pid, "tid": _TID_STEP,
-                "name": f"step {step_idx} [lb={rec['lb']:.4g}, "
-                        f"ub={rec['ub']:.4g})",
-                "ts": step_t0, "dur": t - step_t0, "cat": "step",
-                "args": {"lb": rec["lb"], "ub": rec["ub"],
-                         "st": rec["st"],
-                         "frontier_at_entry": int(rec["frontier"])},
-            })
-            step_idx, step_t0 = step_idx + 1, t
-    if t > step_t0:     # records after the last transition (or none ran)
-        events.append({
-            "ph": "X", "pid": pid, "tid": _TID_STEP,
-            "name": f"step {step_idx}", "ts": step_t0, "dur": t - step_t0,
-            "cat": "step", "args": {},
-        })
-    events.append({
-        "ph": "X", "pid": pid, "tid": _TID_SOLVE, "name": name,
-        "ts": 0, "dur": max(t, 1), "cat": "solve",
-        "args": trace.summary(),
-    })
-    return {"traceEvents": events, "displayTimeUnit": "ms",
-            "otherData": {"time_unit": "logical work (1us ~= 1 relaxation)",
-                          "n_records": trace.n_records,
-                          "dropped": trace.dropped}}
-
-
-def write_perfetto(trace: SolveTrace, path, name: str = "solve") -> None:
-    """Dump :func:`trace_to_perfetto` JSON to ``path``."""
-    with open(path, "w") as f:
-        json.dump(trace_to_perfetto(trace, name=name), f)

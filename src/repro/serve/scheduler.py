@@ -54,6 +54,7 @@ import jax
 
 from .queries import ExecutionPlan, Query, finalize, plan
 from .registry import GraphRegistry
+from ..obs import profiling
 from ..obs.metrics import MetricsRegistry
 
 __all__ = ["DeadlineExceeded", "QueueFull", "QueryScheduler"]
@@ -92,6 +93,7 @@ class _Inflight:
     dist: object                      # device arrays, possibly still computing
     parent: object
     metrics: object
+    t_dispatch: float                 # scheduler clock when it left the queue
 
 
 class QueryScheduler:
@@ -151,6 +153,9 @@ class QueryScheduler:
         self._c_rejected = self.metrics.counter(
             "sssp_scheduler_rejected_total",
             "queries rejected at submit (queue full)", lbl)
+        self._c_queue_wait = self.metrics.counter(
+            "sssp_scheduler_queue_wait_seconds_total",
+            "submit-to-dispatch time of the resolved queries, summed", lbl)
         self._g_pending = self.metrics.gauge(
             "sssp_scheduler_pending", "tickets queued", lbl)
         self._g_inflight = self.metrics.gauge(
@@ -296,32 +301,37 @@ class QueryScheduler:
 
     def _dispatch(self, batch: List[_Ticket]) -> Optional[_Inflight]:
         head = batch[0]
+        t_dispatch = self._clock()
         try:
-            # registry is internally locked with per-key build futures; a
-            # cold build here happens outside the scheduler lock, so
-            # producers (and other gids' batches) keep moving
-            eng = self.registry.engine(head.plan.gid, self.backend,
-                                       device=self.device)
-            if self.ecc_batching and self.max_batch > 1:
-                try:
-                    eng.batch_hint   # pre-pay the landmark BFS off-lock
-                except Exception:
-                    pass             # grouping falls back to FIFO
-            # out-of-range vertex ids must fail loudly here: under jit an
-            # o-o-b scatter is silently dropped and a gather clamps, which
-            # would return a plausible-looking wrong answer
-            batch = [t for t in batch if _check_vertices(t, eng.n)]
-            if not batch:
-                return None
-            head = batch[0]
-            pad = self.max_batch - len(batch)
-            # repeat slot 0 in free slots: static shape, results discarded
-            plans = [t.plan for t in batch] + [head.plan] * pad
-            sources = np.array([t.query.source for t in batch] +
-                               [head.query.source] * pad, np.int32)
-            dist, parent, metrics = eng.run_batch(   # async device dispatch
-                sources, goal=head.plan.goal,
-                goal_params=[p.goal_param for p in plans])
+            with profiling.annotate("repro:sched_dispatch"):
+                # registry is internally locked with per-key build
+                # futures; a cold build here happens outside the
+                # scheduler lock, so producers (and other gids' batches)
+                # keep moving
+                eng = self.registry.engine(head.plan.gid, self.backend,
+                                           device=self.device)
+                if self.ecc_batching and self.max_batch > 1:
+                    try:
+                        eng.batch_hint   # pre-pay the landmark BFS off-lock
+                    except Exception:
+                        pass             # grouping falls back to FIFO
+                # out-of-range vertex ids must fail loudly here: under
+                # jit an o-o-b scatter is silently dropped and a gather
+                # clamps, which would return a plausible-looking wrong
+                # answer
+                batch = [t for t in batch if _check_vertices(t, eng.n)]
+                if not batch:
+                    return None
+                head = batch[0]
+                pad = self.max_batch - len(batch)
+                # repeat slot 0 in free slots: static shape, results
+                # discarded
+                plans = [t.plan for t in batch] + [head.plan] * pad
+                sources = np.array([t.query.source for t in batch] +
+                                   [head.query.source] * pad, np.int32)
+                dist, parent, metrics = eng.run_batch(  # async dispatch
+                    sources, goal=head.plan.goal,
+                    goal_params=[p.goal_param for p in plans])
         except Exception as exc:     # engine failure fails the whole batch
             for t in batch:
                 t.future.set_exception(exc)
@@ -331,45 +341,50 @@ class QueryScheduler:
             self._g_inflight.set(self._inflight_n)
         return _Inflight(batch=batch, eng=eng,
                          sources=sources[:len(batch)],
-                         dist=dist, parent=parent, metrics=metrics)
+                         dist=dist, parent=parent, metrics=metrics,
+                         t_dispatch=t_dispatch)
 
     def _finalize(self, inflight: _Inflight) -> None:
         """Force one dispatched batch to the host and resolve its futures
         (the host half of the double buffer)."""
-        batch, eng = inflight.batch, inflight.eng
-        try:
-            dist = np.asarray(inflight.dist)       # blocks on the device
-            parent = np.asarray(inflight.parent)
-            metrics = jax.tree.map(np.asarray, inflight.metrics)
-        except Exception as exc:
-            for t in batch:
-                t.future.set_exception(exc)
+        with profiling.annotate("repro:sched_finalize"):
+            batch, eng = inflight.batch, inflight.eng
+            try:
+                dist = np.asarray(inflight.dist)       # blocks on the device
+                parent = np.asarray(inflight.parent)
+                metrics = jax.tree.map(np.asarray, inflight.metrics)
+            except Exception as exc:
+                for t in batch:
+                    t.future.set_exception(exc)
+                with self._lock:
+                    self._inflight_n -= len(batch)
+                    self._g_inflight.set(self._inflight_n)
+                return
+            if self.feedback:
+                try:
+                    # measured rounds -> engine batch hints (EMA); padding
+                    # slots are excluded (sources holds real tickets only)
+                    eng.record_rounds(inflight.sources,
+                                      metrics.n_rounds[:len(batch)],
+                                      gamma=self.feedback_gamma)
+                except Exception:
+                    pass                 # a hint failure must not fail results
+            now = self._clock()
+            for slot, t in enumerate(batch):
+                res = finalize(t.query, eng.deg, dist[slot], parent[slot],
+                               _slot_tree(metrics, slot))
+                res.latency_s = now - t.t_submit
+                res.served_by = self.name
+                self._h_latency.observe(res.latency_s)
+                t.future.set_result(res)
             with self._lock:
+                self._c_batches.inc()
+                self._c_done.inc(len(batch))
+                self._c_queue_wait.inc(sum(
+                    max(0.0, inflight.t_dispatch - t.t_submit)
+                    for t in batch))
                 self._inflight_n -= len(batch)
                 self._g_inflight.set(self._inflight_n)
-            return
-        if self.feedback:
-            try:
-                # measured rounds -> engine batch hints (EMA); padding
-                # slots are excluded (sources holds real tickets only)
-                eng.record_rounds(inflight.sources,
-                                  metrics.n_rounds[:len(batch)],
-                                  gamma=self.feedback_gamma)
-            except Exception:
-                pass                 # a hint failure must not fail results
-        now = self._clock()
-        for slot, t in enumerate(batch):
-            res = finalize(t.query, eng.deg, dist[slot], parent[slot],
-                           _slot_tree(metrics, slot))
-            res.latency_s = now - t.t_submit
-            res.served_by = self.name
-            self._h_latency.observe(res.latency_s)
-            t.future.set_result(res)
-        with self._lock:
-            self._c_batches.inc()
-            self._c_done.inc(len(batch))
-            self._inflight_n -= len(batch)
-            self._g_inflight.set(self._inflight_n)
 
     def drain(self, max_steps: int = 10_000) -> int:
         """Synchronously run batches until the queue empties."""

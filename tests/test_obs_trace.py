@@ -11,13 +11,11 @@ The tentpole contract, tested per backend:
 * **ring overflow** — a small-capacity ring keeps the newest records and
   reports the drop, never corrupting retained records;
 * **export invariants** — every ``metrics_dict`` field is present and
-  finite for every backend x ``fused_rounds`` combination, and the
-  Perfetto export is loadable JSON with one round span per record.
+  finite for every backend x ``fused_rounds`` combination.
 
 Distributed (v1/v2/v3 over 8 shards) parity lives in the multidevice
 subprocess test at the bottom, mirroring test_distributed_sssp.py.
 """
-import json
 import math
 import os
 import subprocess
@@ -32,7 +30,7 @@ from repro.core.sssp import (LOGICAL_METRIC_FIELDS, PHYSICAL_METRIC_FIELDS,
                              metrics_dict, sssp)
 from repro.data.generators import kronecker
 from repro.obs import (SolveTrace, TRACE_COLUMNS, TRACE_COUNTER_COLUMNS,
-                       materialize_trace, trace_to_perfetto)
+                       materialize_trace)
 
 # (config kwargs, label) — every single-device engine variant
 BACKENDS = [
@@ -161,29 +159,6 @@ def test_metrics_dict_export_invariants(graph, kw, label):
     if kw.get("fused_rounds"):
         assert d["n_invocations"] >= 1
         assert d["n_invocations"] < d["n_rounds"]   # fusion amortizes
-
-
-def test_perfetto_export_loads(graph, tmp_path):
-    src = int(np.argmax(graph.deg))
-    with Solver.open(graph, EngineConfig(trace=True)) as solver:
-        res = solver.solve(SolveSpec.tree(src))
-    doc = trace_to_perfetto(res.trace, name="unit")
-    # JSON round-trip (what ui.perfetto.dev actually ingests)
-    doc = json.loads(json.dumps(doc))
-    events = doc["traceEvents"]
-    assert events, "empty trace document"
-    spans = [e for e in events if e.get("ph") == "X"]
-    rounds = [e for e in spans if e["tid"] == 2]
-    assert len(rounds) == res.trace.n_records
-    for e in spans:
-        assert e["dur"] >= 1        # zero-width spans are invisible
-        assert set(e) >= {"name", "ph", "ts", "dur", "pid", "tid"}
-    # the step track tiles the solve: one span per transition, plus a
-    # trailing partial span when records follow the last transition
-    n_steps = res.trace.summary()["n_steps"]
-    steps = [e for e in spans if e["tid"] == 1]
-    assert len(steps) in (n_steps, n_steps + 1)
-    assert sum(e["dur"] for e in steps) == sum(e["dur"] for e in rounds)
 
 
 # ----------------------------------------------------------------------
