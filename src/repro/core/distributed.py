@@ -127,6 +127,7 @@ def _dtrace_record(buf, iters, frontier_size, lb, ub, st_, stepped, m0, m1):
         "n_tiles_scanned": m1.n_tiles_scanned - m0.n_tiles_scanned,
         "n_tiles_dense": m1.n_tiles_dense - m0.n_tiles_dense,
         "n_invocations": m1.n_invocations - m0.n_invocations,
+        "n_compact_rounds": m1.n_compact_rounds - m0.n_compact_rounds,
     }
     return trace_append(buf, ivals, fvals)
 

@@ -20,7 +20,11 @@ Registered backends:
 ``segment_min``
     The dense flat-edge-list path (extracted from the original
     ``sssp._relax_round``): one masked ``segment_min`` over all edges plus
-    a min-source winner pass.  Layout = the ``DeviceGraph`` itself.
+    a min-source winner pass.  Layout = the ``DeviceGraph`` itself.  Its
+    compacted round (``relax_compact``, the unbatched single-tier solve
+    and repair) relaxes only the frontier's in-window slots, found by a
+    binary search of each weight-sorted row, and falls back to the
+    dense round when they overflow caps derived from the graph's shapes.
 
 ``blocked_pallas`` (alias ``blocked``)
     The TPU hot path: a :class:`~repro.core.graph.BlockedGraph` layout
@@ -46,7 +50,8 @@ layout-specific and excluded from cross-backend parity.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, NamedTuple
+from functools import partial
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +84,7 @@ class RoundMetrics(NamedTuple):
     n_tiles_scanned: jnp.ndarray  # scalar f32 — edge tiles actually run
     n_tiles_dense: jnp.ndarray    # scalar f32 — dense-grid tile cost
     n_invocations: jnp.ndarray    # scalar f32 — kernel launches (sync units)
+    n_compact: jnp.ndarray        # scalar f32 — 1 if the compacted round ran
 
 
 # ---------------------------------------------------------------------------
@@ -254,10 +260,15 @@ class RelaxBackend:
     ``prepare(graph, **opts)`` builds the backend's layout pytree once per
     graph (host-side, outside ``jit``); ``relax_window(layout, dist,
     parent, frontier, lb, ub)`` executes one synchronized round.
+    ``relax_compact``, where a backend has one, is the same round with a
+    keyword ``caps`` (:func:`compact_caps`) that works in proportion to
+    the frontier; a ``vmap`` would run every one of its branches, so
+    batched solves keep ``relax_window``.
     """
     name: str
     prepare: Callable[..., Any]
     relax_window: Callable[..., Any]
+    relax_compact: Optional[Callable[..., Any]] = None
 
 
 _REGISTRY: dict = {}
@@ -305,20 +316,23 @@ def _segment_min_prepare(g: DeviceGraph, **_opts) -> DeviceGraph:
     return g            # the flat edge list is its own layout
 
 
-def _segment_min_relax(g: DeviceGraph, dist, parent, frontier, lb, ub,
-                       alt_lb=None, prune_bound=None):
-    with profiling.phase("round.gather"):
-        paths = leaf_pruned(frontier, dist, g.deg)
-        cand, in_window, active = edge_candidates(
-            dist[g.src], paths[g.src], parent[g.src], g.dst, g.w, lb, ub)
-        pruned = None
-        if alt_lb is not None:
-            active, pruned = alt_prune(cand, active, alt_lb[g.dst],
-                                       prune_bound)
-            cand = jnp.where(active, cand, INF)
+def _windowed(d_src, f_src, p_src, dst, w, lb, ub, alt_lb, prune_bound):
+    """:func:`edge_candidates` with the ALT cut applied: ``(cand,
+    in_window, active, pruned)`` (``pruned`` is None without ALT)."""
+    cand, in_window, active = edge_candidates(d_src, f_src, p_src, dst, w,
+                                              lb, ub)
+    pruned = None
+    if alt_lb is not None:
+        active, pruned = alt_prune(cand, active, alt_lb[dst], prune_bound)
+        cand = jnp.where(active, cand, INF)
+    return cand, in_window, active, pruned
+
+
+def _settle(g: DeviceGraph, dist, parent, src, dst, cand, in_window, active,
+            pruned, n_compact: float):
+    """Reduce a round's candidates per destination, commit, and count."""
     with profiling.phase("round.reduce"):
-        best, winner = segment_min_with_winner(cand, active, g.src, g.dst,
-                                               g.n)
+        best, winner = segment_min_with_winner(cand, active, src, dst, g.n)
     with profiling.phase("round.apply"):
         new_dist, new_parent, improved = apply_updates(dist, parent, best,
                                                        winner)
@@ -333,13 +347,173 @@ def _segment_min_relax(g: DeviceGraph, dist, parent, frontier, lb, ub,
                       else jnp.sum(pruned.astype(jnp.int32))),
             n_tiles_scanned=jnp.float32(0),
             n_tiles_dense=jnp.float32(0),
-            n_invocations=jnp.float32(0))
+            n_invocations=jnp.float32(0),
+            n_compact=jnp.float32(n_compact))
     return new_dist, new_parent, rm
+
+
+def _segment_min_relax(g: DeviceGraph, dist, parent, frontier, lb, ub,
+                       alt_lb=None, prune_bound=None):
+    with profiling.phase("round.gather"):
+        paths = leaf_pruned(frontier, dist, g.deg)
+        cand, in_window, active, pruned = _windowed(
+            dist[g.src], paths[g.src], parent[g.src], g.dst, g.w, lb, ub,
+            alt_lb, prune_bound)
+    return _settle(g, dist, parent, g.src, g.dst, cand, in_window, active,
+                   pruned, 0.0)
+
+
+def compact_caps(n: int, m: int) -> tuple:
+    """``(kv, c)`` of a compacted round on a graph of ``n`` vertices and
+    ``m`` edge slots: it holds up to ``kv = n / 4`` frontier vertices and
+    ``c`` in-window slots, the least power of two of at least ``m / 64``.
+    """
+    return max(n // 4, 1), 1 << max(-(-m // 64) - 1, 0).bit_length()
+
+
+# A compacted round runs at the smallest of two sizes that holds it: the
+# caps, or the caps over RUNG, since most rounds' frontiers and windows
+# are far below the caps and the round's work scales with its size.
+RUNG = 16
+
+
+def _rungs(cap: int) -> list:
+    return sorted({max(cap // RUNG, 1), cap})
+
+
+def _fit(size, caps, branches, fallback):
+    """Run the first of ``branches`` whose entry of ``caps`` (ascending)
+    holds ``size``, else ``fallback``."""
+    i = sum((size > cap).astype(jnp.int32) for cap in caps)
+    return jax.lax.switch(i, list(branches) + [fallback])
+
+
+def _first(pred, lo, hi, steps: int):
+    """The first index in ``[lo, hi)`` where the monotone ``pred`` holds,
+    or ``hi``: ``steps`` halvings, enough for the longest range."""
+    def step(_, c):
+        lo, hi = c
+        mid = (lo + hi) // 2
+        ok = pred(mid)
+        open_ = lo < hi
+        return (jnp.where(open_ & ~ok, mid + 1, lo),
+                jnp.where(open_ & ok, mid, hi))
+    return jax.lax.fori_loop(0, steps, step, (lo, hi))[0]
+
+
+def compact_frontier(mask, kv: int):
+    """The first ``kv`` ids where ``mask`` holds, in order, 0 past the
+    last.  A small ``kv`` binary-searches the prefix count for each rank
+    (``kv log n`` reads); a large one writes each vertex to its rank."""
+    n = mask.shape[0]
+    cs = jnp.cumsum(mask.astype(jnp.int32))
+    rank = jnp.arange(kv, dtype=jnp.int32)
+    if kv * n.bit_length() >= n:
+        return jnp.zeros((kv,), jnp.int32).at[jnp.where(mask, cs - 1, kv)] \
+            .set(jnp.arange(n, dtype=jnp.int32), mode="drop")
+    at = _first(lambda i: cs.at[i].get(mode="clip") > rank,
+                jnp.zeros_like(rank), jnp.full_like(rank, n), n.bit_length())
+    return jnp.where(rank < cs[-1], at, 0)
+
+
+def row_windows(g: DeviceGraph, d, verts, live, lb, ub):
+    """``(lo, hi)``: the slots of each vertex's row whose candidate
+    ``d + w`` lies in ``[lb, ub)``, as ``[lo, hi)``.
+
+    Rows are sorted by weight (``build_csr``), and for a fixed ``d`` the
+    f32 sum ``d + w`` that :func:`edge_candidates` forms is monotone in
+    ``w``, so the window is one run of the row, bounded by a binary
+    search for the first sum ``>= lb`` and the first ``>= ub``.  A search
+    runs as many steps as the longest row among ``live`` vertices needs;
+    other vertices get an empty range.  The search for ``lb`` runs only
+    when some row's first sum lies below it (the push band at a step's
+    start): every other row is in the window from its first slot.
+    """
+    r0 = g.row_ptr[verts]
+    r1 = jnp.where(live, g.row_ptr[verts + 1], r0)
+    steps = 32 - jax.lax.clz(jnp.max(r1 - r0))
+
+    def first_at_least(bound):
+        return _first(lambda e: d + g.w.at[e].get(mode="clip") >= bound,
+                      r0, r1, steps)
+
+    below = (r0 < r1) & (d + g.w.at[r0].get(mode="clip") < lb)
+    lo = jax.lax.cond(jnp.any(below), lambda: first_at_least(lb),
+                      lambda: r0)
+    return lo, jnp.maximum(first_at_least(ub), lo)
+
+
+def expand_ranges(lo, hi, c: int):
+    """Lay the ranges ``[lo, hi)`` end to end over ``c`` slots: ``(owner,
+    slot, valid)``, where slot ``j`` holds edge ``slot[j]`` of range
+    ``owner[j]`` while ``valid[j]``."""
+    length = hi - lo
+    end = jnp.cumsum(length)
+    off = end - length
+    ids = jnp.arange(lo.shape[0], dtype=jnp.int32)
+    owner = jnp.zeros((c,), jnp.int32).at[
+        jnp.where(length > 0, off, c)].set(ids, mode="drop")
+    owner = jax.lax.cummax(owner)
+    j = jnp.arange(c, dtype=jnp.int32)
+    valid = j < end[-1]
+    return owner, jnp.where(valid, (lo - off)[owner] + j, 0), valid
+
+
+def _segment_min_compact_relax(g: DeviceGraph, dist, parent, frontier, lb,
+                               ub, alt_lb=None, prune_bound=None, *,
+                               caps=None):
+    """The ``segment_min`` round over the frontier's in-window slots only.
+
+    The leaf-pruned frontier is compacted to at most ``kv`` vertices,
+    each row's in-window run is found by :func:`row_windows` and the
+    runs are laid over ``c`` slots (``caps``, default
+    :func:`compact_caps`; each at the smaller :data:`RUNG` size where
+    the round fits it).  The candidates are the ones the dense round
+    leaves finite, so ``dist``, ``parent``, the frontier and every
+    logical counter come out bit for bit as :func:`_segment_min_relax`'s;
+    a round with more vertices or slots than the caps runs that dense
+    round instead.
+    """
+    kv, c = compact_caps(g.n, g.m) if caps is None else caps
+    with profiling.phase("round.compact"):
+        paths = leaf_pruned(frontier, dist, g.deg)
+        count = jnp.sum(paths.astype(jnp.int32))
+
+    def dense():
+        return _segment_min_relax(g, dist, parent, frontier, lb, ub,
+                                  alt_lb, prune_bound)
+
+    def over_slots(verts, d, lo, hi, cc):
+        with profiling.phase("round.compact"):
+            owner, slot, valid = expand_ranges(lo, hi, cc)
+        with profiling.phase("round.gather"):
+            src = verts[owner]
+            dst = g.dst[slot]
+            cand, in_window, active, pruned = _windowed(
+                d[owner], valid, parent[src], dst, g.w[slot], lb, ub,
+                alt_lb, prune_bound)
+        return _settle(g, dist, parent, src, dst, cand, in_window, active,
+                       pruned, 1.0)
+
+    def over_vertices(kvv):
+        with profiling.phase("round.compact"):
+            verts = compact_frontier(paths, kvv)
+            live = jnp.arange(kvv, dtype=jnp.int32) < count
+            d = dist[verts]
+            lo, hi = row_windows(g, d, verts, live, lb, ub)
+            total = jnp.sum(hi - lo)
+        return _fit(total, _rungs(c),
+                    [partial(over_slots, verts, d, lo, hi, cc)
+                     for cc in _rungs(c)], dense)
+
+    return _fit(count, _rungs(kv),
+                [partial(over_vertices, k) for k in _rungs(kv)], dense)
 
 
 SEGMENT_MIN = register_backend(RelaxBackend(
     name="segment_min", prepare=_segment_min_prepare,
-    relax_window=_segment_min_relax))
+    relax_window=_segment_min_relax,
+    relax_compact=_segment_min_compact_relax))
 
 
 # ---------------------------------------------------------------------------
@@ -471,7 +645,8 @@ def _blocked_relax(bg: BlockedGraph, dist, parent, frontier, lb, ub,
         n_pruned=n_pruned,
         n_tiles_scanned=n_tiles.astype(jnp.float32),
         n_tiles_dense=jnp.float32(bg.dense_grid_tiles),
-        n_invocations=jnp.float32(bg.n_blocks))
+        n_invocations=jnp.float32(bg.n_blocks),
+        n_compact=jnp.float32(0))
     return new_dist[:n], new_parent[:n], rm
 
 

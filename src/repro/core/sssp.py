@@ -138,13 +138,15 @@ class SsspMetrics(NamedTuple):
     n_tiles_scanned: jnp.ndarray  # blocked layouts: tiles actually run (f32)
     n_tiles_dense: jnp.ndarray    # blocked layouts: dense-grid cost (f32)
     n_invocations: jnp.ndarray    # kernel launches / sync units (f32)
+    n_compact_rounds: jnp.ndarray  # rounds that ran compacted (f32)
 
 
 # The *physical* counters: layout/launch geometry (0 outside blocked
-# layouts), excluded from cross-backend/engine parity checks.  Everything
+# layouts) and the compacted rounds (0 outside the unbatched segment_min
+# solve), excluded from cross-backend/engine parity checks.  Everything
 # else is logical and must agree bitwise across backends and tiers.
 PHYSICAL_METRIC_FIELDS = ("n_tiles_scanned", "n_tiles_dense",
-                          "n_invocations")
+                          "n_invocations", "n_compact_rounds")
 LOGICAL_METRIC_FIELDS = tuple(f for f in SsspMetrics._fields
                               if f not in PHYSICAL_METRIC_FIELDS)
 
@@ -169,13 +171,17 @@ def _zero_metrics() -> SsspMetrics:
 
 
 def _relax_round(backend: relax.RelaxBackend, layout, st_: SsspState,
-                 alt_lb=None, prune_bound=None) -> SsspState:
+                 alt_lb=None, prune_bound=None, caps=None) -> SsspState:
     """One synchronized round of push-model edge relaxations (Algo 2 l.8-17),
     dispatched through the selected relaxation backend.  ``alt_lb``/
     ``prune_bound`` (p2p with landmarks) enable the ALT goal-directed cut
-    inside the relaxation (see :func:`repro.core.relax.alt_prune`)."""
+    inside the relaxation (see :func:`repro.core.relax.alt_prune`).
+    ``caps`` (:func:`repro.core.relax.compact_caps`) runs the backend's
+    compacted round; None runs its ``relax_window``."""
     with profiling.phase("sssp.round"):
-        new_dist, new_parent, rm = backend.relax_window(
+        fn = (backend.relax_window if caps is None
+              else partial(backend.relax_compact, caps=caps))
+        new_dist, new_parent, rm = fn(
             layout, st_.dist, st_.parent, st_.frontier, st_.lb, st_.ub,
             alt_lb, prune_bound)
         m = st_.metrics
@@ -189,6 +195,7 @@ def _relax_round(backend: relax.RelaxBackend, layout, st_: SsspState,
             n_tiles_scanned=m.n_tiles_scanned + rm.n_tiles_scanned,
             n_tiles_dense=m.n_tiles_dense + rm.n_tiles_dense,
             n_invocations=m.n_invocations + rm.n_invocations,
+            n_compact_rounds=m.n_compact_rounds + rm.n_compact,
         )
     return st_._replace(dist=new_dist, parent=new_parent,
                         frontier=rm.improved, metrics=metrics)
@@ -385,6 +392,7 @@ def _trace_record(s0: SsspState, s1: SsspState, buf):
         "n_tiles_scanned": m1.n_tiles_scanned - m0.n_tiles_scanned,
         "n_tiles_dense": m1.n_tiles_dense - m0.n_tiles_dense,
         "n_invocations": m1.n_invocations - m0.n_invocations,
+        "n_compact_rounds": m1.n_compact_rounds - m0.n_compact_rounds,
     }
     return trace_append(buf, ivals, fvals)
 
@@ -393,7 +401,7 @@ def _run(g: DeviceGraph, layout, source, backend: relax.RelaxBackend,
          max_iters: int, alpha: float, beta: float, goal: str = "tree",
          goal_param=None, fused_rounds: int = 0, fused=None,
          trace_capacity: int = 0, policy: str = "static",
-         alt_data=None, p2p_mode: str = "unidirectional"):
+         alt_data=None, p2p_mode: str = "unidirectional", caps=None):
     """Trace one SSSP computation (shared by sssp / sssp_batch); ``goal``
     selects the early-exit variant (see GOALS).  ``fused_rounds > 0``
     (blocked layouts only) runs each window's rounds through the fused
@@ -406,7 +414,10 @@ def _run(g: DeviceGraph, layout, source, backend: relax.RelaxBackend,
     compiles the exact untraced program.  ``policy`` is static too:
     ``"static"`` compiles the exact pre-policy program, ``"adaptive"``
     carries a :class:`~repro.core.stepping.PolicyState` in the loop and
-    re-sizes the window at each step transition."""
+    re-sizes the window at each step transition.  ``caps`` (static; see
+    :func:`repro.core.relax.compact_caps`) runs the backend's compacted
+    rounds, and only an unbatched solve passes them: under ``vmap``
+    every branch of their switch would run."""
     params = stepping.SteppingParams(alpha=alpha, beta=beta)
     adaptive = policy == "adaptive"
     if policy not in stepping.POLICIES:
@@ -436,7 +447,7 @@ def _run(g: DeviceGraph, layout, source, backend: relax.RelaxBackend,
             raise ConfigError("p2p_mode='bidirectional' supports only "
                               "policy='static' without tracing")
         return _run_bidi(g, layout, source, backend, max_iters, params,
-                         goal_param, fused_rounds, fused, alt_data)
+                         goal_param, fused_rounds, fused, alt_data, caps)
     if alt:
         tgt = jnp.asarray(goal_param, jnp.int32)
         alt_lb = relax.alt_lower_bounds(alt_data.D, tgt, alt_data.delta,
@@ -473,8 +484,8 @@ def _run(g: DeviceGraph, layout, source, backend: relax.RelaxBackend,
             return _fused_relax_rounds(layout, fused, s, fused_rounds)
         if alt:
             return _relax_round(backend, layout, s, alt_lb,
-                                bound_of(s.dist))
-        return _relax_round(backend, layout, s)
+                                bound_of(s.dist), caps)
+        return _relax_round(backend, layout, s, caps=caps)
 
     def body(s: SsspState):
         s = relax_step(s)
@@ -533,7 +544,7 @@ def _run(g: DeviceGraph, layout, source, backend: relax.RelaxBackend,
 
 def _run_bidi(g: DeviceGraph, layout, source, backend, max_iters,
               params: stepping.SteppingParams, target, fused_rounds, fused,
-              alt_data):
+              alt_data, caps=None):
     """Bidirectional meet-in-the-middle p2p (goal="p2p" only).
 
     A forward solve (from ``source``) and a backward solve (from
@@ -582,7 +593,7 @@ def _run_bidi(g: DeviceGraph, layout, source, backend, max_iters,
                                     alt_lb_s, ub_eff, infl, goal_v)
         else:
             s = _relax_round(backend, layout, s, alt_lb_s,
-                             bound_of(s.dist))
+                             bound_of(s.dist), caps)
         s = _bootstrap_ub(g, s, high_d0)
         s = jax.lax.cond(jnp.any(s.frontier),
                          lambda x: x,
@@ -613,6 +624,14 @@ def _run_bidi(g: DeviceGraph, layout, source, backend, max_iters,
     return sf.dist, sf.parent, metrics, None
 
 
+def _compact_caps(backend: relax.RelaxBackend, layout):
+    """The caps of ``backend``'s compacted round on ``layout``
+    (:func:`repro.core.relax.compact_caps`), or None where it has none."""
+    if backend.relax_compact is None:
+        return None
+    return relax.compact_caps(layout.n, layout.m)
+
+
 @partial(jax.jit, static_argnames=("backend", "max_iters", "alpha", "beta",
                                    "goal", "fused_rounds", "trace_capacity",
                                    "policy", "p2p_mode"))
@@ -621,7 +640,8 @@ def _sssp_jit(g, layout, source, backend, max_iters, alpha, beta, goal,
               policy="static", alt_data=None, p2p_mode="unidirectional"):
     return _run(g, layout, source, backend, max_iters, alpha, beta, goal,
                 goal_param, fused_rounds, trace_capacity=trace_capacity,
-                policy=policy, alt_data=alt_data, p2p_mode=p2p_mode)
+                policy=policy, alt_data=alt_data, p2p_mode=p2p_mode,
+                caps=_compact_caps(backend, layout))
 
 
 @partial(jax.jit, static_argnames=("backend", "max_iters", "alpha", "beta",
@@ -644,9 +664,10 @@ def _sssp_batch_jit(g, layout, sources, backend, max_iters, alpha, beta,
     )(sources, goal_params)
 
 
-@partial(jax.jit, static_argnames=("backend", "max_iters", "fused_rounds"))
+@partial(jax.jit, static_argnames=("backend", "max_iters", "fused_rounds",
+                                   "caps"))
 def _repair_jit(layout, dist0, parent0, frontier0, backend, max_iters,
-                fused_rounds):
+                fused_rounds, caps=None):
     fused = relax.fused_slab(layout) if fused_rounds > 0 else None
     init = SsspState(dist=dist0, parent=parent0, frontier=frontier0,
                      lb=jnp.float32(0.0), ub=INF, st=jnp.float32(0.0),
@@ -660,7 +681,7 @@ def _repair_jit(layout, dist0, parent0, frontier0, backend, max_iters,
         if fused_rounds > 0:
             s = _fused_relax_rounds(layout, fused, s, fused_rounds)
         else:
-            s = _relax_round(backend, layout, s)
+            s = _relax_round(backend, layout, s, caps=caps)
         return s._replace(iters=s.iters + 1)
 
     out = jax.lax.while_loop(cond, body, init)
@@ -704,7 +725,7 @@ def repair_relax(layout, dist, parent, frontier, *, backend="segment_min",
         raise ValueError("dist/parent/frontier shapes disagree")
     with profiling.annotate("repro:repair_dispatch"):
         return _repair_jit(layout, dist, parent, frontier, be, max_iters,
-                           fused_rounds)
+                           fused_rounds, _compact_caps(be, layout))
 
 
 def prepare_layout(g: DeviceGraph, backend="segment_min", **backend_opts):
@@ -930,5 +951,6 @@ def normalized_metrics(g_deg, dist, metrics: SsspMetrics) -> dict:
         "n_tiles_scanned": int(metrics.n_tiles_scanned),
         "n_tiles_dense": int(metrics.n_tiles_dense),
         "n_invocations": int(metrics.n_invocations),
+        "n_compact_rounds": int(metrics.n_compact_rounds),
         "reachable": n_reach,
     }

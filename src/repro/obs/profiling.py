@@ -42,6 +42,7 @@ except Exception:                                   # pragma: no cover
 # sub-phase is named ``<outer's second part>.<part>``.
 PHASES = (
     "sssp.round",           # one relaxation round (any backend)
+    "round.compact",        # compact the frontier, bound its rows' windows
     "round.gather",         # dist/frontier/parent at each edge's source
     "round.reduce",         # per-destination min and its winner
     "round.apply",          # commit improvements

@@ -63,11 +63,11 @@ TRACE_I32_COLUMNS = (
 )
 
 # float32 columns: the stepping window at the start of the iteration and
-# the physical (layout/launch geometry) counter deltas, which are f32 in
-# SsspMetrics already.
+# the physical (layout/launch geometry, compacted round) counter deltas,
+# which are f32 in SsspMetrics already.
 TRACE_F32_COLUMNS = (
     "lb", "ub", "st",
-    "n_tiles_scanned", "n_tiles_dense", "n_invocations",
+    "n_tiles_scanned", "n_tiles_dense", "n_invocations", "n_compact_rounds",
 )
 
 TRACE_COLUMNS = TRACE_I32_COLUMNS + TRACE_F32_COLUMNS
@@ -79,7 +79,7 @@ TRACE_COLUMNS = TRACE_I32_COLUMNS + TRACE_F32_COLUMNS
 TRACE_COUNTER_COLUMNS = (
     "n_rounds", "n_steps", "n_extended", "n_trav", "n_pull_trav",
     "n_relax", "n_updates", "n_pruned", "n_tiles_scanned",
-    "n_tiles_dense", "n_invocations",
+    "n_tiles_dense", "n_invocations", "n_compact_rounds",
 )
 
 

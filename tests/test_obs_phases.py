@@ -2,7 +2,8 @@
 serving scheduler's host spans and queue-wait counter.
 
 * every fusion the solve loop runs carries a phase of
-  :data:`repro.obs.profiling.PHASES`, or is the loop's own control;
+  :data:`repro.obs.profiling.PHASES`, or is the loop's own control,
+  the compacted round's search loop and dense fallback included;
 * the phases are metadata: with them stubbed out the compiled program
   is the same, instruction for instruction;
 * a profiler capture of a solve, joined through ``Solver.phase_table``,
@@ -109,6 +110,8 @@ def test_every_loop_fusion_has_a_phase_or_is_loop_control(graph, kind):
             "round.count", "sssp.bootstrap", "sssp.transition",
             "transition.pending", "transition.window",
             "transition.pull"} <= set(table.values())
+    # an unbatched solve compacts its rounds; a batch keeps the dense one
+    assert ("round.compact" in table.values()) == (not spec.batched)
 
 
 def test_phases_add_metadata_only(graph, monkeypatch):
