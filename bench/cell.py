@@ -18,6 +18,19 @@ GRAPH, TRAFFIC, WARM = 0, 1, 2
 CLOSED_LOOP_REQUESTS = 256     # more trees than any window finishes
 
 
+def is_directed(cfg: dict) -> bool:
+    """Whether a configuration's graph is directed (``"undirected":
+    false``; undirected by default): ``(u[i], v[i])`` is then an arc
+    ``u -> v``, never mirrored."""
+    return not cfg.get("undirected", True)
+
+
+def degree(n: int, u, v, directed: bool) -> np.ndarray:
+    """Per-vertex degree; the out-degree where the graph is directed."""
+    deg = np.bincount(u, minlength=n)
+    return deg if directed else deg + np.bincount(v, minlength=n)
+
+
 def rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng(
         np.random.SeedSequence([int(seed) % 2 ** 64, stream]))
@@ -37,8 +50,13 @@ class Inputs:
     warm: List[loadgen.Request]
 
     @property
+    def directed(self) -> bool:
+        return is_directed(self.config)
+
+    @property
     def edges(self) -> int:
-        """Input (undirected) edges, each counted once, as Graph500 does."""
+        """Input edges, each counted once, as Graph500 and GAP count them:
+        the undirected edges, or the arcs of a directed configuration."""
         return int(self.u.shape[0])
 
 
@@ -56,13 +74,13 @@ def build(man: dict, name: str, seed: int, seconds: float,
     mix = loadgen.load(manifest.traffic_path(wl["traffic"]))
     if rate_qps is not None:
         mix = dict(mix, rate_qps=rate_qps)
+    directed = is_directed(cfg)
     n, u, v, w = gen.generate(cfg, rng(seed, GRAPH))
-    graph = {"n": n,
-             "deg": np.bincount(u, minlength=n) + np.bincount(v, minlength=n),
+    graph = {"n": n, "deg": degree(n, u, v, directed),
              "max_w": float(np.max(w.astype(np.float32)))}
 
     def trial(r, count):
-        return gen.trial_sources(n, u, v, r, count)
+        return gen.trial_sources(n, u, v, r, count, directed=directed)
 
     if mix["loop"] == "open":
         count = loadgen.open_count(mix, seconds)

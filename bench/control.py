@@ -49,7 +49,8 @@ def readings(man: dict, workload: str, seed: int, seconds: float,
              trees: int = 4, rehearse: bool = False) -> dict:
     """The control's numbers for one seed, as ``compare`` counts them."""
     inp = cell_mod.build(man, workload, seed, seconds, rehearse=rehearse)
-    adj = reference.adjacency(inp.n, inp.u, inp.v, inp.w)
+    adj = reference.adjacency(inp.n, inp.u, inp.v, inp.w,
+                              directed=inp.directed)
     low = adj.astype(LOWER)
     if inp.mix["loop"] == "closed":
         nums = {"dist_mismatch": 0, "parent_bad": 0, "answers_missing": 0}

@@ -66,8 +66,12 @@ def generate(cfg: dict, rng: np.random.Generator):
     return side * side, label[u], label[v], w
 
 
-def trial_sources(n, u, v, rng: np.random.Generator, count: int):
-    """``count`` roots: the stratified design, repeated."""
+def trial_sources(n, u, v, rng: np.random.Generator, count: int,
+                  directed: bool = False):
+    """``count`` roots: the stratified design, repeated.  A lattice is
+    undirected: a directed grid configuration is refused."""
+    if directed:
+        raise ValueError("the grid generator makes undirected lattices only")
     side = int(round(np.sqrt(n)))
     base = _first_seen(*_lattice(side))
     rank = np.empty(n, np.int64)

@@ -10,9 +10,12 @@ As in GAP, the graph and its trial roots are fixed: the edges and
 weights come from the configuration's ``structure_seed``, and the roots
 are drawn uniformly, with GAP's fixed root seed, from the largest
 connected component (so that every trial solves a whole tree), in a
-fixed order.  ``--seed`` draws the vertex labelling, Graph500's
-permutation step: every seed solves the same trees on an isomorphic
-graph laid out differently in memory.  With random roots a window's
+fixed order.  A directed configuration (``"undirected": false``) keeps
+the same list as arcs ``u -> v``, and its roots come from the largest
+strongly connected component: each tree then reaches that component
+and everything it points to.  ``--seed`` draws the vertex labelling,
+Graph500's permutation step: every seed solves the same trees on an
+isomorphic graph laid out differently in memory.  With random roots a window's
 five trees varied by about 6% in rounds from seed to seed (PERF.md).
 
 ``generate`` returns plain ``(n, u, v, w)`` arrays, and
@@ -73,12 +76,14 @@ def _structure(cfg: dict, rng: np.random.Generator):
     return n, u, v, w
 
 
-def trial_sources(n, u, v, rng: np.random.Generator, count: int):
+def trial_sources(n, u, v, rng: np.random.Generator, count: int,
+                  directed: bool = False):
     """``count`` roots: the fixed design of ``N_ROOTS``, repeated."""
     from scipy.sparse import coo_matrix
     from scipy.sparse.csgraph import connected_components
     adj = coo_matrix((np.ones(u.shape[0], np.int8), (u, v)), shape=(n, n))
-    _, comp = connected_components(adj, directed=False)
+    _, comp = connected_components(adj, directed=directed,
+                                   connection="strong")
     seq = np.column_stack([u, v]).ravel()
     labels, first = np.unique(seq, return_index=True)
     by_first = labels[np.argsort(first)]        # the label-free numbering

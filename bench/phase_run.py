@@ -3,12 +3,9 @@ the solve's named phases.
 
     python3 bench/phase_run.py --workload <tree cell> --seed <n> --seconds <s>
 
-The window is ``run.py``'s closed loop on the same inputs and solver.
-After it, the solver's phase table for the cell's tree spec
-(``Solver.phase_table``, timed) joins the trace's device ops to the
-phases (``trace_phases.py``).  Every program is compiled with its
-``op_name`` metadata in the persistent cache's key, so that an entry
-written by a program without phases cannot serve this one.  The last
+The window, the phase table (timed) and the trace's reduction are
+``run.py``'s under ``--trace 1``, on the same inputs and solver, with
+every op of the window kept and not the ten largest.  The last
 line of standard output is a JSON object: ``phases_s`` (phase ->
 ``[seconds, runs]``), ``round_ms.tree`` and ``transition_ms.tree``
 (``metrics/``), the traced window's ``teps`` and ``ms_per_round.tree``,
@@ -20,9 +17,7 @@ runs at the generator's tiny size on the CPU.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
-import shutil
 import sys
 import tempfile
 import time
@@ -34,7 +29,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
         sys.path.insert(0, _p)
 
 from bench import cell as cell_mod  # noqa: E402
-from bench import manifest, trace_phases, trace_reduce  # noqa: E402
+from bench import manifest, trace_reduce  # noqa: E402
 from bench import run as harness  # noqa: E402
 
 READ = ("round_ms.tree", "transition_ms.tree", "teps", "ms_per_round.tree")
@@ -50,11 +45,7 @@ def main(argv=None) -> int:
                     help="CPU run at the generator's tiny size")
     args = ap.parse_args(argv)
     import jax
-    from repro.api import SolveSpec
-    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
-    if not args.rehearse:
-        jax.config.update("jax_compilation_cache_dir", str(harness.CACHE_DIR))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    harness.configure_jax(args.rehearse, trace=True)
     devices = jax.devices()
     if not args.rehearse and devices[0].platform != "tpu":
         print("phase_run needs a TPU (or --rehearse)", file=sys.stderr)
@@ -75,16 +66,12 @@ def main(argv=None) -> int:
             out = harness.closed_window(solver, inp.requests, args.seconds)
         jax.profiler.stop_trace()
         t0 = time.perf_counter()
-        table = solver.phase_table(SolveSpec.tree(inp.requests[0].source))
+        table = harness.phase_table(solver, inp)
         table_s = time.perf_counter() - t0
     finally:
         solver.close()
-    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
     trace_reduce.TOP = 1 << 30            # every op, not the ten largest
-    reduced = trace_reduce.reduce_trace(path, **{
-        k: v for k, v in where.items() if k != "op_stat"})
-    phased = trace_phases.reduce_phases(path, table, **where)
-    shutil.rmtree(trace_dir, ignore_errors=True)
+    reduced, phased = harness.read_trace(trace_dir, table, **where)
     rec = {"loop": "closed", "trace": reduced, "phases": phased["phases"],
            "trees": out["trees"], "edges": inp.edges,
            "window_s": out["window_s"]}

@@ -1,9 +1,10 @@
 """Plain shortest-path reference: a binary-heap Dijkstra over numpy rows.
 
 Independent of the program: it builds its own adjacency from the
-generator's undirected ``(u, v, w)`` list (one edge per ordered pair,
-the lightest of any parallel edges) and computes in the precision it
-is given.  In float32 every tentative distance is ``dist[x] + w``
+generator's ``(u, v, w)`` list (one edge per ordered pair, the lightest
+of any parallel edges; each edge both ways, or each arc ``u -> v``
+alone where the graph is directed) and computes in the precision it is
+given.  In float32 every tentative distance is ``dist[x] + w``
 rounded to float32, the sum the configuration states, so distances
 are comparable bit for bit with any float32 engine that relaxes
 ``dist[u] + w``.  The same code in bfloat16 is the lower-precision
@@ -22,7 +23,7 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class Adjacency:
-    """CSR of an undirected graph, rows sorted by neighbour id."""
+    """CSR of out-arcs, rows sorted by neighbour id."""
     n: int
     row_ptr: np.ndarray
     col: np.ndarray
@@ -43,11 +44,17 @@ class Adjacency:
         return dataclasses.replace(self, w=self.w.astype(dtype))
 
 
-def adjacency(n: int, u, v, w, dtype=np.float32) -> Adjacency:
-    """Symmetrized CSR with the lightest of any parallel edges kept."""
-    s = np.concatenate([np.asarray(u, np.int64), np.asarray(v, np.int64)])
-    d = np.concatenate([np.asarray(v, np.int64), np.asarray(u, np.int64)])
-    ww = np.concatenate([np.asarray(w), np.asarray(w)]).astype(dtype)
+def adjacency(n: int, u, v, w, dtype=np.float32,
+              directed: bool = False) -> Adjacency:
+    """CSR with the lightest of any parallel edges kept: symmetrized, or
+    with ``directed`` the arcs ``u -> v`` as they are."""
+    u, v, w = np.asarray(u, np.int64), np.asarray(v, np.int64), np.asarray(w)
+    if directed:
+        s, d, ww = u, v, w.astype(dtype)
+    else:
+        s = np.concatenate([u, v])
+        d = np.concatenate([v, u])
+        ww = np.concatenate([w, w]).astype(dtype)
     keep = s != d
     s, d, ww = s[keep], d[keep], ww[keep]
     order = np.lexsort((ww.astype(np.float64), d, s))

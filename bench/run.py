@@ -20,7 +20,13 @@ the plain reference (``compare.py``); the numbers compared and their
 limits go to the last lines of standard error and under ``checks``,
 the last key of the result line, which is the last line of standard
 output.  ``--trace 1`` records a profiler trace of the window and
-reports the per-layer metrics in place of the end-to-end ones.
+reports the per-layer metrics in place of the end-to-end ones; on the
+single tier it also charges the window's device time to the solve's
+named phases (``Solver.phase_table``, ``trace_phases.py``), reported
+as ``extra.phases_s``.
+
+A configuration with ``"undirected": false`` is directed: the program
+gets its arcs unmirrored, and the reference judges by the arcs alone.
 
 Without a TPU, or with fewer chips than the cell asks for, it exits 3
 and prints no result.  ``--rehearse`` runs the cell on the CPU at the
@@ -53,8 +59,6 @@ import numpy as np  # noqa: E402
 from bench import cell as cell_mod  # noqa: E402
 from bench import compare, manifest  # noqa: E402
 
-COUNTERS = ("n_rounds", "n_steps", "n_trav", "n_pull_trav", "n_relax",
-            "n_updates")
 GRACE_S = 60.0
 EXIT_NO_CHIP = 3
 CACHE_DIR = ROOT / ".jax_cache"
@@ -107,17 +111,39 @@ def _spec(req):
     return SolveSpec.bounded(req.source, float(req.param))
 
 
+def configure_jax(rehearse: bool, trace: bool):
+    """Before the first compile: the persistent compile cache in a fixed
+    directory of the checkout (the path is part of the key); and, in a
+    traced run, the programs' ``op_name`` metadata in the key, so that
+    an entry written by a program without phases cannot serve one whose
+    phase table is read.  Untraced runs keep their key."""
+    import jax
+    if trace:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
+    if not rehearse:
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+
+
+def host_graph(inp):
+    """The program's CSR (``build_csr``) of the cell's edge list: each
+    edge both ways, or each arc alone where the configuration is
+    directed."""
+    from repro.core.graph import build_csr
+    return build_csr(inp.n, inp.u, inp.v, inp.w, symmetrize=not inp.directed)
+
+
 def _warm_closed(solver, inp, engine_config):
     """Compile each kind's program without a whole solve: from a root
-    with no edge the loop ends at once.  The cell's own solver serves
-    where its graph has such a vertex; otherwise a graph of the same
-    shapes whose root 0 has none."""
+    with no (out-)edge the loop ends at once.  The cell's own solver
+    serves where its graph has such a vertex; otherwise a graph of the
+    same shapes, directed alike, whose root 0 has none."""
     import dataclasses
     from repro.api import Solver
     from repro.core.graph import build_csr
     first = {r.kind: r for r in reversed(inp.requests)}
-    deg = np.bincount(inp.u, minlength=inp.n) + \
-        np.bincount(inp.v, minlength=inp.n)
+    deg = cell_mod.degree(inp.n, inp.u, inp.v, inp.directed)
     isolated = np.flatnonzero(deg == 0)
     if isolated.size:
         root, warm = int(isolated[0]), solver
@@ -125,7 +151,7 @@ def _warm_closed(solver, inp, engine_config):
         m = inp.edges
         root, warm = 0, Solver.open(
             build_csr(inp.n, np.ones(m, np.int64), np.full(m, 2, np.int64),
-                      np.ones(m)), engine_config)
+                      np.ones(m), symmetrize=not inp.directed), engine_config)
     try:
         for req in first.values():
             warm.solve(_spec(dataclasses.replace(req, source=root))
@@ -143,10 +169,9 @@ def open_solver(inp, devices, grace_s: float = GRACE_S, phases=None):
     the program.  ``phases`` gets the end of each step on the clock of
     ``T_PROCESS``."""
     from repro.api import EngineConfig, Solver
-    from repro.core.graph import build_csr
     phases = {} if phases is None else phases
     mix = inp.mix
-    host = build_csr(inp.n, inp.u, inp.v, inp.w)
+    host = host_graph(inp)
     phases["csr"] = time.perf_counter() - T_PROCESS
     if mix["tier"] == "routed":
         cfg = EngineConfig(tier="routed", devices=tuple(devices),
@@ -171,7 +196,9 @@ def open_solver(inp, devices, grace_s: float = GRACE_S, phases=None):
 
 
 def closed_window(solver, requests, seconds: float) -> dict:
-    """One tree in flight; ends with the first tree done after ``seconds``."""
+    """One tree in flight; ends with the first tree done after ``seconds``.
+    Each tree's answer and every field of its ``SsspMetrics`` come back
+    in one transfer."""
     import jax
     trees, answers, failed = [], [], 0
     t_start = t_end = time.perf_counter()
@@ -183,12 +210,12 @@ def closed_window(solver, requests, seconds: float) -> dict:
                 jax.block_until_ready((res.dist, res.parent, res.metrics))
                 t1 = time.perf_counter()
             with _span("bench:fetch"):
-                answers.append((req, np.asarray(res.dist),
-                                np.asarray(res.parent)))
-                trees.append(dict(
-                    source=req.source, seconds=t1 - t0,
-                    **{f: int(np.asarray(getattr(res.metrics, f)))
-                       for f in COUNTERS}))
+                dist, parent, metrics = jax.device_get(
+                    (res.dist, res.parent, res.metrics))
+                answers.append((req, dist, parent))
+                trees.append(dict(source=req.source, seconds=t1 - t0,
+                                  **{f: getattr(metrics, f).item()
+                                     for f in metrics._fields}))
         except Exception as exc:             # a failed tree is counted
             print(f"tree from {req.source} failed: {exc!r}", file=sys.stderr)
             failed += 1
@@ -265,15 +292,20 @@ def open_window(solver, requests, seconds: float, grace_s: float) -> dict:
 
 
 def check(inp, out) -> dict:
-    """The numbers that decide ``correct`` (see ``compare.py``)."""
+    """The numbers that decide ``correct`` (see ``compare.py``).  A
+    root's reference tree is computed once, however often the window
+    solved it."""
     from bench import reference
-    adj = reference.adjacency(inp.n, inp.u, inp.v, inp.w)
+    adj = reference.adjacency(inp.n, inp.u, inp.v, inp.w,
+                              directed=inp.directed)
     if inp.mix["loop"] == "closed":
         nums = {"dist_mismatch": 0, "parent_bad": 0}
+        refs = {}
         for req, dist, parent in out["answers"]:
-            ref, _, _ = reference.dijkstra(adj, req.source)
+            if req.source not in refs:
+                refs[req.source] = reference.dijkstra(adj, req.source)[0]
             for k, v in compare.tree_numbers(adj, req.source, dist, parent,
-                                             ref).items():
+                                             refs[req.source]).items():
                 nums[k] += v
         nums["answers_missing"] = out["failed"]
         return nums
@@ -286,6 +318,32 @@ def check(inp, out) -> dict:
         wrong += compare.query_wrong(adj, req.kind, req.source, req.param,
                                      answer)
     return {"answers_wrong": int(wrong), "answers_missing": out["failed"]}
+
+
+def phase_table(solver, inp):
+    """The phase table (``Solver.phase_table``) of the program that the
+    cell's first request runs, or ``None`` off the single tier."""
+    if inp.mix["tier"] != "single":
+        return None
+    return solver.phase_table(_spec(inp.requests[0]))
+
+
+def read_trace(trace_dir, table=None, **where):
+    """``(reduced, phased)`` of the window traced into ``trace_dir``:
+    ``trace_reduce.reduce_trace``'s reduction, and with a phase table
+    ``trace_phases.reduce_phases``'s device time by phase (else
+    ``None``).  ``where`` names the planes and lines of a trace that is
+    not a TPU's.  The trace is removed."""
+    from bench import trace_phases, trace_reduce
+    try:
+        path = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)[0]
+        reduced = trace_reduce.reduce_trace(
+            path, **{k: v for k, v in where.items() if k != "op_stat"})
+        phased = None if table is None else \
+            trace_phases.reduce_phases(path, table, **where)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    return reduced, phased
 
 
 def _memory_peak(devices) -> int:
@@ -304,10 +362,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     wl = manifest.workload(man, workload)
     import jax
     phases = {"import": time.perf_counter() - T_PROCESS}
-    if not rehearse:
-        # a fixed directory of the checkout: the path is part of the key
-        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    configure_jax(rehearse, trace)
     devices = jax.devices()
     phases["jax"] = time.perf_counter() - T_PROCESS
     platform = devices[0].platform
@@ -346,8 +401,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             else:
                 out = open_window(solver, inp.requests, seconds, grace_s)
         compiles.armed = False
+        table = None
         if trace:
             jax.profiler.stop_trace()
+            table = phase_table(solver, inp)
         counters = None
         if before is not None:
             after = _scheduler_counters(solver)
@@ -358,17 +415,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     del solver
     gc.collect()
 
-    reduced = None
+    reduced = phased = None
     if trace:
-        files = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-        from bench.trace_reduce import reduce_trace
-        reduced = reduce_trace(files[0])
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        reduced, phased = read_trace(trace_dir, table)
+    phases_s = phased["phases"] if phased else None
 
     rec = {"cell": workload, "loop": mix["loop"], "setup_s": setup_s,
            "window_s": out["window_s"], "edges": inp.edges,
            "trees": out.get("trees", []), "queries": out.get("queries", []),
-           "counters": counters, "trace": reduced, "peaks": peaks}
+           "counters": counters, "trace": reduced, "phases": phases_s,
+           "peaks": peaks}
     metrics = {}
     for m in manifest.cell_metrics(man, workload,
                                    "per_layer" if trace else "end_to_end"):
@@ -392,6 +448,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                        "compiles_in_window": compiles.count,
                        "late_s_max": out.get("late_s_max"),
                        "setup_phases_s": phases}
+    if trace:
+        result["extra"]["phases_s"] = phases_s
     result["checks"] = {k: {"value": v, "limit": compare.LIMITS[k]}
                         for k, v in numbers.items()}
     return 0, result

@@ -2,7 +2,9 @@
 
 Checksums of tiny graphs and streams, so a change to the benchmark's
 data shows here.  Nothing is compared with the program's generators:
-the program may change its own freely.
+the program may change its own freely.  A directed Kronecker graph
+(``directed=True``) is the same edge list, kept as arcs, with its roots
+in the largest strongly connected component.
 """
 import hashlib
 
@@ -28,19 +30,48 @@ def digest(*arrays) -> str:
     return h.hexdigest()[:16]
 
 
-@pytest.mark.parametrize("mod,cfg,n,m,want,trial", [
-    (kronecker, KRON, 64, 256, "fb18c948e23d9547",
+@pytest.mark.parametrize("mod,cfg,directed,n,m,want,trial", [
+    (kronecker, KRON, False, 64, 256, "fb18c948e23d9547",
      [33, 50, 16, 10, 35, 1, 45, 8]),
-    (grid, GRID, 64, 112, "90c3ece7edc6fb8a",
+    (kronecker, KRON, True, 64, 256, "fb18c948e23d9547",
+     [4, 20, 16, 43, 52, 1, 45, 52]),
+    (grid, GRID, False, 64, 112, "90c3ece7edc6fb8a",
      [9, 27, 25, 11, 57, 43, 41, 59]),
 ])
-def test_graph_and_roots_are_fixed_by_the_seed(mod, cfg, n, m, want, trial):
+def test_graph_and_roots_are_fixed_by_the_seed(mod, cfg, directed, n, m,
+                                               want, trial):
     got_n, u, v, w = mod.generate(cfg, cell.rng(12345, cell.GRAPH))
     assert (got_n, u.shape[0]) == (n, m)
     assert not (u == v).any()
     assert digest(u, v, w) == want
-    roots = mod.trial_sources(got_n, u, v, cell.rng(12345, 1), 8)
+    roots = mod.trial_sources(got_n, u, v, cell.rng(12345, 1), 8,
+                              directed=directed)
     assert roots.tolist() == trial
+
+
+def _reach(n, u, v) -> np.ndarray:
+    """``r[a, b]``: b is reachable from a over the arcs (a dense closure)."""
+    r = np.eye(n, dtype=bool)
+    r[u, v] = True
+    while True:
+        nxt = (r.astype(np.int32) @ r.astype(np.int32)) > 0
+        if np.array_equal(nxt, r):
+            return r
+        r = nxt
+
+
+@pytest.mark.parametrize("seed", [1, 2 ** 31 + 9])
+def test_directed_roots_lie_in_the_largest_strong_component(seed):
+    n, u, v, _ = kronecker.generate(dict(KRON, scale=8),
+                                    cell.rng(seed, cell.GRAPH))
+    r = _reach(n, u, v)
+    strong = r & r.T                  # a row is its vertex's component
+    size = strong.sum(axis=1)
+    roots = kronecker.trial_sources(n, u, v, None, kronecker.N_ROOTS,
+                                    directed=True)
+    assert (size[roots] == size.max()).all()
+    assert strong[roots[0]][roots].all()       # one component
+    assert size.max() < r[roots[0]].sum()      # which reaches further
 
 
 def test_kronecker_weights_lie_in_the_half_open_unit_interval():
@@ -63,21 +94,29 @@ def test_kronecker_seeds_relabel_one_graph_and_its_roots():
     assert seen[0] == seen[1] == seen[2]
 
 
-@pytest.mark.parametrize("mod,cfg", [(kronecker, dict(KRON, scale=8)),
-                                     (grid, dict(GRID, side=16))])
-def test_every_seed_solves_the_same_trees(mod, cfg):
+@pytest.mark.parametrize("mod,cfg,directed", [
+    (kronecker, dict(KRON, scale=8), False),
+    (kronecker, dict(KRON, scale=8), True),
+    (grid, dict(GRID, side=16), False)])
+def test_every_seed_solves_the_same_trees(mod, cfg, directed):
     """Seeds relabel one graph: each design root's reference distances are
     the same multiset under every seed."""
     from bench import reference
     first = None
     for seed in (1, 2, 3, 4):
         n, u, v, w = mod.generate(cfg, cell.rng(seed, cell.GRAPH))
-        adj = reference.adjacency(n, u, v, w)
-        roots = mod.trial_sources(n, u, v, None, 4)
+        adj = reference.adjacency(n, u, v, w, directed=directed)
+        roots = mod.trial_sources(n, u, v, None, 4, directed=directed)
         got = [np.sort(reference.dijkstra(adj, int(r))[0]).tolist()
                for r in roots]
         first = got if first is None else first
         assert got == first
+
+
+def test_grid_refuses_a_directed_graph():
+    n, u, v, _ = grid.generate(GRID, cell.rng(0, cell.GRAPH))
+    with pytest.raises(ValueError, match="undirected"):
+        grid.trial_sources(n, u, v, None, 4, directed=True)
 
 
 def test_grid_roots_keep_their_eccentricity_under_every_seed():

@@ -4,16 +4,18 @@ contract line with ``correct`` true.  Without ``--rehearse`` a CPU-only
 machine gets no result, and a rehearsal that asks for device metrics
 (``--trace 1``) fails rather than reporting them.  The held-back
 serving cell (``held_back.py``) rehearses in process through the same
-``run_cell``.
+``run_cell``; ``bench/phase_run.py`` rehearses its traced window.
 """
 import json
 import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 import held_back
+from bench import cell as cell_mod
 from bench import manifest, run
 
 MAN = manifest.load()
@@ -72,6 +74,57 @@ def test_no_accelerator_gives_no_result():
              "--trace", "0")
     assert p.returncode != 0
     assert not _json_lines(p.stdout)
+
+
+def test_window_fetches_every_counter_in_one_transfer_per_tree(monkeypatch):
+    """Each tree's record holds every ``SsspMetrics`` field as a plain
+    number, from one ``jax.device_get`` with its answer; ``n_rounds``
+    is the solve's own."""
+    import jax
+    from repro.api import SolveSpec
+    from repro.core.sssp import SsspMetrics
+    inp = cell_mod.build(MAN, CELLS[-1], 7, 1.0, rehearse=True)
+    solver = run.open_solver(inp, jax.devices()[:1])
+    real, calls = jax.device_get, []
+
+    def counted(x):
+        calls.append(x)
+        return real(x)
+
+    try:
+        monkeypatch.setattr(jax, "device_get", counted)
+        out = run.closed_window(solver, inp.requests[:3], 1e9)
+        monkeypatch.undo()
+        direct = solver.solve(SolveSpec.tree(inp.requests[0].source))
+    finally:
+        solver.close()
+    assert len(out["trees"]) == len(calls) == 3 and out["failed"] == 0
+    for t in out["trees"]:
+        assert set(t) == {"source", "seconds", *SsspMetrics._fields}
+        assert all(type(t[f]) in (int, float) for f in SsspMetrics._fields)
+    assert out["trees"][0]["n_rounds"] == int(np.asarray(
+        direct.metrics.n_rounds)) > 0
+    assert out["trees"][0]["n_compact_rounds"] == float(np.asarray(
+        direct.metrics.n_compact_rounds))
+
+
+def test_phase_run_rehearses_and_keeps_its_keys():
+    p = subprocess.run(
+        [sys.executable, str(manifest.HERE / "phase_run.py"), "--workload",
+         CELLS[-1], "--seed", "4294967311", "--seconds", "1", "--rehearse"],
+        cwd=manifest.ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-2000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert set(line) == {
+        "cell", "seed", "device", "phases_s", "leaf_s", "device_ops_s",
+        "busy_s", "window_s", "trees", "failed", "n_rounds", "n_steps",
+        "phase_table_s", "table_size", "round_ms.tree",
+        "transition_ms.tree", "teps", "ms_per_round.tree"}
+    assert line["failed"] == 0 and line["trees"] >= 1
+    assert line["round_ms.tree"] > 0 and line["transition_ms.tree"] > 0
+    assert sum(s for s, _ in line["phases_s"].values()) == \
+        pytest.approx(line["leaf_s"])
 
 
 @pytest.mark.parametrize("cell", held_back.CELLS)

@@ -8,11 +8,12 @@ XLA's ops run on host threads, one line each, beside the thread pool's
 own events, so the reduction reads the events that carry ``hlo_op``.
 """
 import json
+import shutil
 from pathlib import Path
 
 import pytest
 
-from bench import manifest, trace_phases
+from bench import manifest, run, trace_phases
 from bench.trace_reduce import _leaves, _op_name
 from repro.obs.profiling import PHASES, PhaseTable
 
@@ -129,6 +130,22 @@ def test_readers_read_time_per_run(reduced):
         secs, runs = trace_phases.group(reduced["phases"], outer)
         mod = manifest.load_module(manifest.metric_path(name))
         assert mod.read(rec) == pytest.approx(1e3 * secs / runs)
+
+
+def test_a_traced_run_reads_the_phases_that_feed_the_readers(
+        table, reduced, tmp_path):
+    """``run.read_trace``, as a ``--trace 1`` run calls it after its
+    window: its phases, which become ``rec["phases"]``, give both
+    readers their values; the trace is removed."""
+    shutil.copy(TRACE, tmp_path / TRACE.name)
+    trace, phased = run.read_trace(tmp_path, table, **CPU)
+    assert not tmp_path.exists()
+    assert phased == reduced and trace["busy_s"] > 0
+    rec = {"trace": trace, "phases": phased["phases"]}
+    for name, outer in zip(READERS, ("sssp.round", "sssp.transition")):
+        secs, runs = trace_phases.group(phased["phases"], outer)
+        got = manifest.load_module(manifest.metric_path(name)).read(rec)
+        assert got == pytest.approx(1e3 * secs / runs) and got > 0
 
 
 def test_op_names_match_the_table(reduced, table):
