@@ -229,6 +229,7 @@ class SolveResult:
     tier: str
     served_by: Optional[Any] = None     # routed: per-slot scheduler names
     trace: Optional[Any] = None         # SolveTrace | list[SolveTrace]
+    directed: bool = False              # the graph's mark (its leaves)
 
     def __iter__(self):
         return iter((self.dist, self.parent, self.metrics))
@@ -320,7 +321,8 @@ class SolveResult:
             if slot is None:
                 raise ValueError("batched result: pass slot=")
             m = jax.tree.map(lambda x: np.asarray(x)[slot], m)
-        return normalized_metrics(self.deg, self._slot(self.dist, slot), m)
+        return normalized_metrics(self.deg, self._slot(self.dist, slot), m,
+                                  self.directed)
 
 
 class Solver:
@@ -344,6 +346,7 @@ class Solver:
         self._host = graph
         self.deg = np.asarray(graph.deg)
         self.n = int(self.deg.shape[0])
+        self.directed = graph.directed
         self._closed = False
         if self.tier == "single":
             self._open_single(graph, layout)
@@ -433,7 +436,8 @@ class Solver:
     def _check_layout(self, layout) -> None:
         """A foreign layout must match the configured backend *and* cover
         the whole graph — a shard slice or an unpadded/mis-sized blocked
-        layout would silently drop edges under ``jit``."""
+        layout would silently drop edges under ``jit`` — and carry the
+        graph's ``directed`` mark, which decides how its slots are read."""
         r = self.resolved
         if r.backend == "blocked_pallas":
             if not isinstance(layout, BlockedGraph):
@@ -476,6 +480,10 @@ class Solver:
                     f" m={layout.m} max_w={float(layout.max_w):.6g} vs "
                     f"n={self.n} m={int(self._host.m)} "
                     f"max_w={float(self._host.max_w):.6g})")
+        if layout.directed != self.directed:
+            raise ConfigError(
+                f"layout directed={layout.directed} does not match the "
+                f"graph's directed={self.directed}")
 
     def _open_sharded(self, graph):
         from .core.distributed import (blocked_specs, graph_specs,
@@ -590,7 +598,7 @@ class Solver:
                     spec=spec, dist=np.asarray(out.dist)[sl],
                     parent=np.asarray(out.parent)[sl],
                     metrics=metrics, deg=self.deg, tier=self.tier,
-                    trace=trace)
+                    trace=trace, directed=self.directed)
         return results
 
     def _goal_args(self, spec: SolveSpec) -> dict:
@@ -616,7 +624,7 @@ class Solver:
         dist, parent, metrics, trace = self._materialize_trace(out)
         return SolveResult(spec=spec, dist=dist, parent=parent,
                            metrics=metrics, deg=self.deg, tier=self.tier,
-                           trace=trace)
+                           trace=trace, directed=self.directed)
 
     def phase_table(self, spec: SolveSpec) -> profiling.PhaseTable:
         """``{instruction_name: phase}`` of the program that solving

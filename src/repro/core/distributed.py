@@ -63,7 +63,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import relax, stats, stepping, traversal
-from .config import EngineConfig, as_resolved, resolve_interpret
+from .config import (ConfigError, EngineConfig, as_resolved,
+                     resolve_interpret)
 from .graph import (DEFAULT_BLOCK_V, DEFAULT_TILE_E, BlockedEdges,
                     HostGraph, bucket_tiles, check_layout_bytes,
                     layout_bytes, shard_block_v, slice_for_shard)
@@ -119,6 +120,7 @@ def _dtrace_record(buf, iters, frontier_size, lb, ub, st_, stepped, m0, m1):
         "n_trav": m1.n_trav - m0.n_trav,
         "n_pull_trav": m1.n_pull_trav - m0.n_pull_trav,
         "n_relax": m1.n_relax - m0.n_relax,
+        "n_pull_relax": m1.n_pull_relax - m0.n_pull_relax,
         "n_updates": m1.n_updates - m0.n_updates,
         "n_pruned": m1.n_pruned - m0.n_pruned,
     }
@@ -147,8 +149,19 @@ class ShardedGraph(NamedTuple):
     n_true: jnp.ndarray    # scalar int32 — real vertex count (pre-padding)
 
 
+def _refuse_directed(g, what: str) -> None:
+    """The sharded engines are built for undirected graphs (their pull
+    is a mirrored push, their leaves have degree 1): a directed graph
+    raises :class:`~repro.core.config.ConfigError` here."""
+    if getattr(g, "directed", False):
+        raise ConfigError(f"{what}: the sharded tier solves undirected "
+                          "graphs only, and this one is directed; use the "
+                          "single or routed tier")
+
+
 def shard_graph(g: HostGraph, n_shards: int) -> ShardedGraph:
     """Host-side partitioner: block vertex ownership, edges by src owner."""
+    _refuse_directed(g, "shard_graph")
     p = n_shards
     block = -(-g.n // p)          # ceil
     n_pad = block * p
@@ -270,6 +283,7 @@ def shard_blocked(g, n_shards: Optional[int] = None, *,
     size is checked against ``max_bytes`` (default: the device's memory)
     before any slab is built.
     """
+    _refuse_directed(g, "shard_blocked")
     if use_kernel is None:
         use_kernel = True
     interpret = resolve_interpret(interpret)
@@ -725,6 +739,7 @@ def _v1_body(n_pad, block, axes, params, max_iters, goal="tree", batch=False,
                 n_extended=metrics.n_extended +
                 jnp.sum((improved & (deg > 1)).astype(jnp.int32)),
                 n_relax=metrics.n_relax + requests,
+                n_pull_relax=metrics.n_pull_relax + requests,
                 n_updates=metrics.n_updates +
                 jnp.sum(improved.astype(jnp.int32)),
                 n_pruned=metrics.n_pruned + n_prn,
@@ -1373,6 +1388,7 @@ def _v2_body(n_pad, block, axes, params, max_iters, fused_rounds,
                 n_pull_trav=metrics.n_pull_trav + scans,
                 n_extended=metrics.n_extended + nl_upd,
                 n_relax=metrics.n_relax + reqs,
+                n_pull_relax=metrics.n_pull_relax + reqs,
                 n_updates=metrics.n_updates + upd,
                 n_pruned=metrics.n_pruned + n_prn,
                 n_rounds=metrics.n_rounds + 1)

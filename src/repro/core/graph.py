@@ -8,8 +8,11 @@ The paper (§4.1) preprocesses every graph by
 Host-side construction is done in numpy (the data pipeline is not a TPU
 workload); the jit-facing container :class:`DeviceGraph` is a NamedTuple of
 jnp arrays so it can flow through ``jax.jit`` / ``shard_map`` unchanged.
-Undirected graphs are stored with both edge directions (the paper symmetrizes
-directed GAPBS graphs the same way).
+An undirected graph stores every edge in both directions; a directed one
+(``build_csr(..., symmetrize=False)``) stores each arc ``u -> v`` once, in
+``u``'s row.  Which of the two a graph is, is its ``directed`` mark: a
+Python bool that rides in the pytree's aux data, so the engines specialize
+on it at compile time.
 """
 from __future__ import annotations
 
@@ -35,15 +38,22 @@ N_DEG_BUCKETS = EXACT_DEG + 26
 
 
 class DeviceGraph(NamedTuple):
-    """Immutable device-resident CSR + flat-edge-list graph."""
+    """Immutable device-resident CSR + flat-edge-list graph.
+
+    ``directed`` is static (pytree aux data, not a leaf): ``False`` when
+    the slots hold every edge both ways, ``True`` when each slot is one
+    arc ``src -> dst`` and nothing is mirrored.
+    """
     src: jnp.ndarray       # [M] int32 — source of each directed edge slot
     dst: jnp.ndarray       # [M] int32 — destination
     w: jnp.ndarray         # [M] float32 — weight (sorted ascending within row)
     row_ptr: jnp.ndarray   # [N+1] int32 — CSR offsets into (dst, w)
-    deg: jnp.ndarray       # [N] int32 — vertex degree (directed slot count)
+    deg: jnp.ndarray       # [N] int32 — out-degree (slots in the row)
     rtow: jnp.ndarray      # [RATIO_NUM] float32 — weight quantile LUT
     max_w: jnp.ndarray     # scalar float32 — maxW(G, 1)
-    n_edges2: jnp.ndarray  # scalar int32 — 2|E| (directed slot count)
+    n_edges2: jnp.ndarray  # scalar int32 — M, the slot count (sum of deg):
+    #                        2|E| undirected, the arc count directed
+    directed: bool = False
 
     @property
     def n(self) -> int:
@@ -54,9 +64,17 @@ class DeviceGraph(NamedTuple):
         return self.src.shape[0]
 
 
+jax.tree_util.register_pytree_node(
+    DeviceGraph,
+    lambda g: (tuple(g[:-1]), g.directed),
+    lambda directed, arrays: DeviceGraph(*arrays, directed=directed),
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class HostGraph:
-    """numpy-side graph (builder product; converted once per run)."""
+    """numpy-side graph (builder product; converted once per run).
+    ``directed`` as in :class:`DeviceGraph`."""
     n: int
     src: np.ndarray
     dst: np.ndarray
@@ -65,14 +83,11 @@ class HostGraph:
     deg: np.ndarray
     rtow: np.ndarray
     max_w: float
+    directed: bool = False
 
     @property
     def m(self) -> int:
         return int(self.src.shape[0])
-
-    @property
-    def n_edges_undirected(self) -> int:
-        return self.m // 2
 
     def to_device(self) -> DeviceGraph:
         return DeviceGraph(
@@ -84,6 +99,7 @@ class HostGraph:
             rtow=jnp.asarray(self.rtow, jnp.float32),
             max_w=jnp.float32(self.max_w),
             n_edges2=jnp.int32(self.m),
+            directed=self.directed,
         )
 
 
@@ -102,12 +118,15 @@ weight_quantile_lut = _weight_quantile_lut
 
 def build_csr(n: int, eu: np.ndarray, ev: np.ndarray, ew: np.ndarray,
               symmetrize: bool = True) -> HostGraph:
-    """Build the preprocessed CSR from an undirected edge list.
+    """Build the preprocessed CSR from an edge list.
 
-    ``(eu[i], ev[i], ew[i])`` is one undirected edge; both directions are
-    stored.  Per-vertex adjacency is sorted by weight ascending (paper §4.1
-    preprocessing), which lets the kernel bound in-window edges with a binary
-    search instead of a scan.
+    With ``symmetrize`` (the default) ``(eu[i], ev[i], ew[i])`` is one
+    undirected edge, both directions are stored, and the graph is marked
+    undirected.  Without it, it is one arc ``eu[i] -> ev[i]``, stored
+    once, and the graph is marked directed: the engines then relax arcs
+    only forwards.  Per-vertex adjacency is sorted by weight ascending
+    (paper §4.1 preprocessing), which lets the kernel bound in-window
+    edges with a binary search instead of a scan.
     """
     eu = np.asarray(eu, np.int64)
     ev = np.asarray(ev, np.int64)
@@ -124,8 +143,8 @@ def build_csr(n: int, eu: np.ndarray, ev: np.ndarray, ew: np.ndarray,
     deg = np.bincount(s, minlength=n).astype(np.int32)
     row_ptr = np.zeros(n + 1, np.int64)
     np.cumsum(deg, out=row_ptr[1:])
-    # RtoW is built from the *undirected* weight multiset; the directed store
-    # duplicates every weight so quantiles are identical either way.
+    # RtoW is built from the stored slots; symmetrizing duplicates every
+    # weight, so an undirected graph's quantiles are its edges' own.
     rtow = _weight_quantile_lut(w)
     return HostGraph(
         n=n,
@@ -136,6 +155,7 @@ def build_csr(n: int, eu: np.ndarray, ev: np.ndarray, ew: np.ndarray,
         deg=deg,
         rtow=rtow,
         max_w=float(w.max()) if w.size else 0.0,
+        directed=not symmetrize,
     )
 
 
@@ -179,7 +199,8 @@ class BlockedGraph:
     (:func:`slice_for_shard`) covers only its own source block range
     (``src_base = shard * block``) while destinations stay global.
     Static layout parameters are pytree aux data (shapes stay static under
-    ``jax.jit``); only the arrays are traced.
+    ``jax.jit``); only the arrays are traced.  ``directed`` is the source
+    graph's mark (:class:`DeviceGraph`), which the leaf rule follows.
     """
     n: int                               # true vertex count (pre-padding)
     block_v: int
@@ -192,6 +213,7 @@ class BlockedGraph:
     dense_grid_tiles: int                # per-round cost of the dense scan
     slabs: Tuple[BlockedEdges, ...]      # one slab per source block
     deg: jnp.ndarray                     # [n_blocks * block_v] int32, 0-padded
+    directed: bool = False
 
     @property
     def n_pad(self) -> int:
@@ -209,12 +231,12 @@ jax.tree_util.register_pytree_node(
     lambda bg: ((bg.slabs, bg.deg),
                 (bg.n, bg.block_v, bg.n_blocks, bg.n_dst_blocks,
                  bg.src_base, bg.tile_e, bg.use_kernel, bg.interpret,
-                 bg.dense_grid_tiles)),
+                 bg.dense_grid_tiles, bg.directed)),
     lambda aux, ch: BlockedGraph(n=aux[0], block_v=aux[1], n_blocks=aux[2],
                                  n_dst_blocks=aux[3], src_base=aux[4],
                                  tile_e=aux[5], use_kernel=aux[6],
                                  interpret=aux[7], dense_grid_tiles=aux[8],
-                                 slabs=ch[0], deg=ch[1]),
+                                 directed=aux[9], slabs=ch[0], deg=ch[1]),
 )
 
 
@@ -409,7 +431,7 @@ def build_blocked(g, *, block_v: int = DEFAULT_BLOCK_V,
                         n_dst_blocks=n_blocks, src_base=0, tile_e=tile_e,
                         use_kernel=use_kernel, interpret=interpret,
                         dense_grid_tiles=dense_tiles, slabs=tuple(slabs),
-                        deg=jnp.asarray(deg_pad))
+                        deg=jnp.asarray(deg_pad), directed=g.directed)
 
 
 def shard_block_v(block: int, block_v: int) -> int:

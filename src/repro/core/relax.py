@@ -56,6 +56,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from .config import ConfigError
 from .graph import DeviceGraph, BlockedGraph, build_blocked
 from ..kernels.edge_relax.ops import relax_bucket, relax_fused, relax_partials
 from ..obs import profiling
@@ -91,10 +92,18 @@ class RoundMetrics(NamedTuple):
 # shared relaxation primitives
 # ---------------------------------------------------------------------------
 
+def nonleaf(deg: jnp.ndarray, directed: bool = False) -> jnp.ndarray:
+    """Vertices whose pop can relax anything.  Undirected, a vertex of
+    degree 1 is a leaf: its one edge leads back to its parent, which it
+    cannot improve.  Directed, its one arc may lead onward, so only a
+    vertex with no out-arc is a leaf.  ``directed`` is static."""
+    return deg > (0 if directed else 1)
+
+
 def leaf_pruned(frontier: jnp.ndarray, dist: jnp.ndarray,
-                deg: jnp.ndarray) -> jnp.ndarray:
+                deg: jnp.ndarray, directed: bool = False) -> jnp.ndarray:
     """Algo 2 l.8: paths reaching a leaf are never extended."""
-    return frontier & ((dist <= 0.0) | (deg > 1))
+    return frontier & ((dist <= 0.0) | nonleaf(deg, directed))
 
 
 def edge_candidates(d_src, f_src, p_src, dst, w, lb, ub):
@@ -342,7 +351,8 @@ def _settle(g: DeviceGraph, dist, parent, src, dst, cand, in_window, active,
             n_trav=jnp.sum(in_window.astype(jnp.int32)),
             n_relax=jnp.sum(active.astype(jnp.int32)),
             n_updates=jnp.sum(improved.astype(jnp.int32)),
-            n_extended=jnp.sum((improved & (g.deg > 1)).astype(jnp.int32)),
+            n_extended=jnp.sum((improved & nonleaf(g.deg, g.directed))
+                               .astype(jnp.int32)),
             n_pruned=(jnp.int32(0) if pruned is None
                       else jnp.sum(pruned.astype(jnp.int32))),
             n_tiles_scanned=jnp.float32(0),
@@ -355,7 +365,7 @@ def _settle(g: DeviceGraph, dist, parent, src, dst, cand, in_window, active,
 def _segment_min_relax(g: DeviceGraph, dist, parent, frontier, lb, ub,
                        alt_lb=None, prune_bound=None):
     with profiling.phase("round.gather"):
-        paths = leaf_pruned(frontier, dist, g.deg)
+        paths = leaf_pruned(frontier, dist, g.deg, g.directed)
         cand, in_window, active, pruned = _windowed(
             dist[g.src], paths[g.src], parent[g.src], g.dst, g.w, lb, ub,
             alt_lb, prune_bound)
@@ -476,7 +486,7 @@ def _segment_min_compact_relax(g: DeviceGraph, dist, parent, frontier, lb,
     """
     kv, c = compact_caps(g.n, g.m) if caps is None else caps
     with profiling.phase("round.compact"):
-        paths = leaf_pruned(frontier, dist, g.deg)
+        paths = leaf_pruned(frontier, dist, g.deg, g.directed)
         count = jnp.sum(paths.astype(jnp.int32))
 
     def dense():
@@ -606,7 +616,7 @@ def _blocked_relax(bg: BlockedGraph, dist, parent, frontier, lb, ub,
     dist_p = jnp.pad(dist, (0, pad), constant_values=jnp.inf)
     parent_p = jnp.pad(parent, (0, pad), constant_values=-1)
     frontier_p = jnp.pad(frontier, (0, pad))
-    paths = leaf_pruned(frontier_p, dist_p, bg.deg)
+    paths = leaf_pruned(frontier_p, dist_p, bg.deg, bg.directed)
     alt_p = None if alt_lb is None else jnp.pad(
         alt_lb, (0, bg.n_out - alt_lb.shape[0]), constant_values=jnp.inf)
 
@@ -641,7 +651,8 @@ def _blocked_relax(bg: BlockedGraph, dist, parent, frontier, lb, ub,
         n_trav=n_trav,
         n_relax=n_relax,
         n_updates=jnp.sum(improved.astype(jnp.int32)),
-        n_extended=jnp.sum((improved & (bg.deg[:n] > 1)).astype(jnp.int32)),
+        n_extended=jnp.sum((improved & nonleaf(bg.deg[:n], bg.directed))
+                           .astype(jnp.int32)),
         n_pruned=n_pruned,
         n_tiles_scanned=n_tiles.astype(jnp.float32),
         n_tiles_dense=jnp.float32(bg.dense_grid_tiles),
@@ -704,7 +715,14 @@ def blocked_fused_rounds(bg: BlockedGraph, fs: FusedSlab, dist, parent,
     ``min(prune_ub, dist[prune_tgt] * prune_infl)`` — exactly what the
     unfused path computes per round — so fused and unfused pruning
     decisions (and the ``n_pruned`` counter) stay bitwise-identical.
+
+    The kernel's leaf rule is the undirected one, so a directed layout
+    raises :class:`~repro.core.config.ConfigError`.
     """
+    if bg.directed:
+        raise ConfigError("fused_rounds runs the undirected leaf rule in "
+                          "its kernel and cannot solve a directed graph; "
+                          "drop fused_rounds")
     if bg.n_pad != bg.n_out or bg.src_base != 0:
         raise ValueError(
             "the fused megakernel needs a whole-graph blocked layout "

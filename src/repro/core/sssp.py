@@ -133,6 +133,7 @@ class SsspMetrics(NamedTuple):
     n_trav: jnp.ndarray        # edge traversals, push model ("nTrav" raw part)
     n_pull_trav: jnp.ndarray   # edge traversals, pull model (requests)
     n_relax: jnp.ndarray       # relaxation attempts (created paths)
+    n_pull_relax: jnp.ndarray  # the pull phase's part of n_relax (requests)
     n_updates: jnp.ndarray     # successful relaxations (dist improvements)
     n_pruned: jnp.ndarray      # candidates cut by the ALT goal-directed bound
     n_tiles_scanned: jnp.ndarray  # blocked layouts: tiles actually run (f32)
@@ -249,35 +250,51 @@ def _bootstrap_ub(g: DeviceGraph, st_: SsspState,
 def _pull_phase(g: DeviceGraph, dist, parent, st, lb, ub, metrics,
                 alt_lb=None, prune_bound=None):
     """Function 1's pull phase: settled band [st, lb) answers requests from
-    unsettled vertices (built from the shared relax primitives).  Under
-    ALT the *requester* (``g.src``) is the vertex receiving the update,
-    so requests with ``cand + alt_lb[src] > prune_bound`` are cut."""
-    dv = dist[g.dst]
-    # edges a pull scan touches: requester unsettled, weight short enough
-    scan = (dist[g.src] > lb) & (g.w < ub - st)
-    # requests created (responder side; w < ub - st is implied)
-    mask = (dv >= st) & (dv < lb) & (dv + g.w < ub)
-    cand = jnp.where(mask, dv + g.w, INF)
-    n_pruned = jnp.int32(0)
-    if alt_lb is not None:
-        mask, pruned = relax.alt_prune(cand, mask, alt_lb[g.src],
-                                       prune_bound)
-        cand = jnp.where(mask, cand, INF)
-        n_pruned = jnp.sum(pruned.astype(jnp.int32))
-    best, winner = relax.segment_min_with_winner(cand, mask, g.dst, g.src,
-                                                 g.n)
-    new_dist, new_parent, improved = relax.apply_updates(
-        dist, parent, best, winner, gate=dist > lb)
-    nonleaf_upd = improved & (g.deg > 1)
-    metrics = metrics._replace(
-        n_pull_trav=metrics.n_pull_trav + jnp.sum(scan.astype(jnp.int32)),
-        n_extended=metrics.n_extended +
-        jnp.sum(nonleaf_upd.astype(jnp.int32)),
-        n_relax=metrics.n_relax + jnp.sum(mask.astype(jnp.int32)),
-        n_updates=metrics.n_updates + jnp.sum(improved.astype(jnp.int32)),
-        n_pruned=metrics.n_pruned + n_pruned,
-        n_rounds=metrics.n_rounds + 1,  # the pull phase is a round/sync
-    )
+    unsettled vertices (built from the shared relax primitives).
+
+    An undirected graph's slot ``a -> b`` is read as its mirror ``b ->
+    a``: the requester ``a`` (``g.src``) receives from the responder
+    ``b`` (``g.dst``), so each requester scans its own row.  A directed
+    graph has no mirrors, so each arc ``u -> v`` is read forwards: the
+    tail answers and the head receives (``src`` and ``dst`` swapped; no
+    reverse CSR).  On a symmetric slot set both readings give the same
+    (requester, responder, candidate) triples.  Under ALT the requester
+    is the vertex receiving the update, so requests with ``cand +
+    alt_lb[requester] > prune_bound`` are cut."""
+    requester, responder = (g.dst, g.src) if g.directed else (g.src, g.dst)
+    with profiling.phase("transition.pull.scan"):
+        dv = dist[responder]
+        # slots a pull scan touches: requester unsettled, weight short enough
+        scan = (dist[requester] > lb) & (g.w < ub - st)
+        # requests created (responder side; w < ub - st is implied)
+        mask = (dv >= st) & (dv < lb) & (dv + g.w < ub)
+        cand = jnp.where(mask, dv + g.w, INF)
+        n_pruned = jnp.int32(0)
+        if alt_lb is not None:
+            mask, pruned = relax.alt_prune(cand, mask, alt_lb[requester],
+                                           prune_bound)
+            cand = jnp.where(mask, cand, INF)
+            n_pruned = jnp.sum(pruned.astype(jnp.int32))
+    with profiling.phase("transition.pull.reduce"):
+        best, winner = relax.segment_min_with_winner(cand, mask, responder,
+                                                     requester, g.n)
+    with profiling.phase("transition.pull.apply"):
+        new_dist, new_parent, improved = relax.apply_updates(
+            dist, parent, best, winner, gate=dist > lb)
+        nonleaf_upd = improved & relax.nonleaf(g.deg, g.directed)
+        requests = jnp.sum(mask.astype(jnp.int32))
+        metrics = metrics._replace(
+            n_pull_trav=metrics.n_pull_trav
+            + jnp.sum(scan.astype(jnp.int32)),
+            n_extended=metrics.n_extended +
+            jnp.sum(nonleaf_upd.astype(jnp.int32)),
+            n_relax=metrics.n_relax + requests,
+            n_pull_relax=metrics.n_pull_relax + requests,
+            n_updates=metrics.n_updates
+            + jnp.sum(improved.astype(jnp.int32)),
+            n_pruned=metrics.n_pruned + n_pruned,
+            n_rounds=metrics.n_rounds + 1,  # the pull phase is a round/sync
+        )
     return new_dist, new_parent, metrics
 
 
@@ -384,6 +401,7 @@ def _trace_record(s0: SsspState, s1: SsspState, buf):
         "n_trav": m1.n_trav - m0.n_trav,
         "n_pull_trav": m1.n_pull_trav - m0.n_pull_trav,
         "n_relax": m1.n_relax - m0.n_relax,
+        "n_pull_relax": m1.n_pull_relax - m0.n_pull_relax,
         "n_updates": m1.n_updates - m0.n_updates,
         "n_pruned": m1.n_pruned - m0.n_pruned,
     }
@@ -440,6 +458,11 @@ def _run(g: DeviceGraph, layout, source, backend: relax.RelaxBackend,
     source = jnp.asarray(source, jnp.int32)
     alt = alt_data is not None and goal == "p2p"
     if goal == "p2p" and p2p_mode == "bidirectional":
+        if g.directed:
+            raise ConfigError("p2p_mode='bidirectional' searches backwards "
+                              "over the forward arcs, which is exact only "
+                              "on an undirected graph; this one is "
+                              "directed")
         if not alt:
             raise ConfigError("p2p_mode='bidirectional' needs a landmark "
                               "set (use_alt=True / landmarks=...)")
@@ -928,14 +951,15 @@ def metrics_dict(metrics: SsspMetrics) -> dict:
     return out
 
 
-def normalized_metrics(g_deg, dist, metrics: SsspMetrics) -> dict:
-    """Paper §4 normalizations: nFrontier, nSync, nTrav (host-side)."""
-    import numpy as np
+def normalized_metrics(g_deg, dist, metrics: SsspMetrics,
+                       directed: bool = False) -> dict:
+    """Paper §4 normalizations: nFrontier, nSync, nTrav (host-side);
+    ``directed`` is the graph's mark, which decides its leaves."""
     deg = np.asarray(g_deg)
     d = np.asarray(dist)
     reach = np.isfinite(d)
     n_reach = max(int(reach.sum()), 1)
-    nonleaf = max(int((reach & (deg > 1)).sum()), 1)
+    nonleaf = max(int((reach & relax.nonleaf(deg, directed)).sum()), 1)
     logn = max(np.log2(max(deg.shape[0], 2)), 1.0)
     return {
         "nFrontier": float(metrics.n_extended) / nonleaf,
@@ -946,6 +970,7 @@ def normalized_metrics(g_deg, dist, metrics: SsspMetrics) -> dict:
         "n_steps": int(metrics.n_steps),
         "n_rounds": int(metrics.n_rounds),
         "n_relax": int(metrics.n_relax),
+        "n_pull_relax": int(metrics.n_pull_relax),
         "n_updates": int(metrics.n_updates),
         "n_pruned": int(metrics.n_pruned),
         "n_tiles_scanned": int(metrics.n_tiles_scanned),

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import distributed, graph
+from ..core.config import ConfigError
 from .edits import (AppliedDelta, EdgeDelta, KIND_ADD, KIND_DECREASE,
                     KIND_INCREASE, KIND_REMOVE, KIND_SAME)
 
@@ -56,7 +57,15 @@ def patch_host(hg: graph.HostGraph,
     float32 and promoted to float64 only for the quantile LUT, matching
     the builder's float64 pipeline exactly (the promotion is monotone,
     so the stable sort permutation is identical too).
+
+    Edits apply to undirected graphs only: a directed ``hg`` raises
+    :class:`~repro.core.config.ConfigError`.
     """
+    if hg.directed:
+        raise ConfigError("patch_host: edge deltas apply to undirected "
+                          "graphs only, and this one is directed; build "
+                          "the edited arc list anew with build_csr(..., "
+                          "symmetrize=False)")
     n = hg.n
     s = np.asarray(hg.src, np.int64)
     d = np.asarray(hg.dst, np.int64)
@@ -120,7 +129,7 @@ def patch_host(hg: graph.HostGraph,
         n=n, src=s2.astype(np.int32), dst=d2.astype(np.int32), w=w2,
         row_ptr=rp.astype(np.int32), deg=deg,
         rtow=graph.weight_quantile_lut(w2.astype(np.float64)),
-        max_w=float(w2.max()) if w2.size else 0.0)
+        max_w=float(w2.max()) if w2.size else 0.0, directed=hg.directed)
     return new_host, applied
 
 

@@ -39,7 +39,9 @@ except Exception:                                   # pragma: no cover
 
 # The solve loop's phases, outer before inner.  ``round.*`` nest inside
 # ``sssp.round`` and ``transition.*`` inside ``sssp.transition``: a
-# sub-phase is named ``<outer's second part>.<part>``.
+# sub-phase is named ``<outer's second part>.<part>``.  A sub-phase of a
+# sub-phase keeps the whole name of its outer one
+# (``transition.pull.scan``), so it still counts as a ``transition.*``.
 PHASES = (
     "sssp.round",           # one relaxation round (any backend)
     "round.compact",        # compact the frontier, bound its rows' windows
@@ -52,6 +54,9 @@ PHASES = (
     "transition.pending",   # smallest pending path length
     "transition.window",    # computeST and the next window's gap
     "transition.pull",      # Function 1's pull phase
+    "transition.pull.scan",    # responder/requester gathers and masks
+    "transition.pull.reduce",  # per-requester min and its winner
+    "transition.pull.apply",   # commit improvements, count
 )
 
 

@@ -58,6 +58,7 @@ TRACE_I32_COLUMNS = (
     "n_trav",
     "n_pull_trav",
     "n_relax",
+    "n_pull_relax",
     "n_updates",
     "n_pruned",
 )
@@ -78,7 +79,7 @@ TRACE_COLUMNS = TRACE_I32_COLUMNS + TRACE_F32_COLUMNS
 # reproduces the final SsspMetrics field exactly.
 TRACE_COUNTER_COLUMNS = (
     "n_rounds", "n_steps", "n_extended", "n_trav", "n_pull_trav",
-    "n_relax", "n_updates", "n_pruned", "n_tiles_scanned",
+    "n_relax", "n_pull_relax", "n_updates", "n_pruned", "n_tiles_scanned",
     "n_tiles_dense", "n_invocations", "n_compact_rounds",
 )
 

@@ -974,7 +974,7 @@ class GraphRegistry:
                     dst=np.asarray(spec.dst), w=np.asarray(spec.w),
                     row_ptr=np.asarray(spec.row_ptr),
                     deg=np.asarray(spec.deg), rtow=np.asarray(spec.rtow),
-                    max_w=float(spec.max_w))
+                    max_w=float(spec.max_w), directed=spec.directed)
             old_host = spec
             with profiling.annotate(f"repro:apply_delta:{gid}"):
                 new_host, applied = patch_host(old_host, edits)

@@ -104,15 +104,17 @@ def test_fused_megakernel_is_refused_compiled():
                          0.5, 1.0, interpret=False)
 
 
-def _kron_shapes(sharding, scale: int, edge_factor: int = 16):
-    """A ``DeviceGraph`` of shapes for ``kronecker(scale, edge_factor)``."""
+def _kron_shapes(sharding, scale: int, edge_factor: int = 16,
+                 directed: bool = False):
+    """A ``DeviceGraph`` of shapes for ``kronecker(scale, edge_factor)``:
+    each edge both ways, or each arc once where ``directed``."""
     n = 1 << scale
-    m = 2 * edge_factor * n
+    m = (1 if directed else 2) * edge_factor * n
     S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     return DeviceGraph(src=S((m,), I32), dst=S((m,), I32), w=S((m,), F32),
                        row_ptr=S((n + 1,), I32), deg=S((n,), I32),
                        rtow=S((RATIO_NUM,), F32), max_w=S((), F32),
-                       n_edges2=S((), I32))
+                       n_edges2=S((), I32), directed=directed)
 
 
 @pytest.mark.parametrize("batch", [0, 2], ids=["single", "batch2"])
@@ -134,3 +136,20 @@ def test_segment_min_solve_compiles_at_scale22(one_chip, no_cache, batch):
     # argument bytes count it twice; the buffers are shared at run time
     assert mem.argument_size_in_bytes // 2 + mem.temp_size_in_bytes \
         < HBM_BYTES
+
+
+def test_directed_solve_compiles_for_the_kron17d_cell(one_chip, no_cache):
+    """The directed cell's program (the pull over arcs read forwards, the
+    out-degree-0 leaf rule) at its 2,097,152 arcs fits one chip's HBM,
+    and the mark alone makes it another program than the undirected
+    one on the same shapes."""
+    be = relax.get_backend("segment_min")
+    S = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    g = _kron_shapes(one_chip, 17, directed=True)
+    compiled = {d: sssp._sssp_jit.lower(
+        g._replace(directed=d), g._replace(directed=d), S, be, 1_000_000,
+        3.0, 0.9, "tree", S).compile() for d in (True, False)}
+    mem = compiled[True].memory_analysis()
+    assert mem.argument_size_in_bytes // 2 + mem.temp_size_in_bytes \
+        < HBM_BYTES
+    assert compiled[True].as_text() != compiled[False].as_text()
